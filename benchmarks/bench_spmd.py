@@ -245,30 +245,46 @@ def main(argv=None) -> dict:
 def run(quick: bool = True):
     """benchmarks/run.py harness contract: (name, us_per_call, derived).
 
-    Executed in a fresh subprocess: the forced host device count must be
-    set before jax initializes, which the harness process already did.
-    Trace / metrics / platform requests reach the child through the
+    On a CPU host this executes in a fresh subprocess: the forced host
+    device count must be set before jax initializes, which the harness
+    process already did. On an accelerator the harness process holds the
+    chips, and a child could not take them, so the bench runs in-process
+    on the real devices, or fails when there are too few. Trace /
+    metrics / platform requests arrive through the
     ``REPRO_BENCH_TRACE`` / ``REPRO_BENCH_METRICS`` /
     ``REPRO_BENCH_PLATFORM`` env vars (the ``run(quick)`` signature is
-    fixed by the harness), forwarded as the child's own CLI flags.
+    fixed by the harness), forwarded as the bench's own CLI flags.
     """
     import json
-    cmd = [sys.executable, os.path.abspath(__file__)]
-    if quick:
-        cmd.append("--quick")
+
+    import jax
+    flags = ["--quick"] if quick else []
     for var, flag in (("REPRO_BENCH_TRACE", "--trace"),
                       ("REPRO_BENCH_METRICS", "--metrics"),
                       ("REPRO_BENCH_PLATFORM", "--platform")):
         val = os.environ.get(var)
         if val:
-            cmd += [flag, val]
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)        # let the module force its own devices
-    subprocess.run(cmd, check=True, env=env,
-                   cwd=os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(os.path.dirname(__file__), "..", "experiments",
-                           "bench", "BENCH_spmd.json")) as f:
-        payload = json.load(f)
+            flags += [flag, val]
+    backend = jax.default_backend()
+    if backend != "cpu":
+        need = max(WORKER_COUNTS) * max(MESH_MODELS)
+        have = jax.device_count()
+        if have < need:
+            raise RuntimeError(
+                f"bench_spmd needs {need} {backend} devices for its "
+                f"(W={max(WORKER_COUNTS)}, mesh_model={max(MESH_MODELS)}) "
+                f"cell; this host has {have}")
+        payload = main(flags)
+    else:
+        env = dict(os.environ)
+        env.pop("XLA_FLAGS", None)    # let the module force its own devices
+        subprocess.run([sys.executable, os.path.abspath(__file__)] + flags,
+                       check=True, env=env,
+                       cwd=os.path.dirname(os.path.abspath(__file__)))
+        with open(os.path.join(os.path.dirname(__file__), "..",
+                               "experiments", "bench",
+                               "BENCH_spmd.json")) as f:
+            payload = json.load(f)
     rows = [(f"spmd.{r['backend']}_w{r['workers']}_chunk{r['chunk_size']}"
              f"_m{r['mesh_model']}",
              1e6 / r["steps_per_s"], f"{r['steps_per_s']:.1f}steps/s")

@@ -322,8 +322,6 @@ class ExecutionConfig:
     # TPU (where it runs natively), the jnp dot elsewhere — interpret-
     # mode Pallas is pure overhead off-TPU (docs/spmd.md, BENCH_spmd)
     use_kernel: Optional[bool] = None
-    # Pallas interpret mode: None = auto (interpret off TPU), or forced
-    interpret: Optional[bool] = None
     # per-worker gradient batching inside each 'data' shard: 0 = vmap ALL
     # local workers (one fused program, the fast path when activation
     # memory allows), 1 = sequential lax.map (one worker's activations at

@@ -79,14 +79,10 @@ MODEL_AXIS = "model"
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions: jax.shard_map (>= 0.6, check_vma)
-    where it exists, else jax.experimental.shard_map (check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """``jax.shard_map`` without the varying-manual-axes check (the
+    bodies psum explicitly)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -121,13 +117,6 @@ def validate_layout(num_workers: int, global_batch: int,
             f"global_batch ({global_batch}) must be divisible by "
             f"total_workers ({num_workers})")
     return num_workers // mesh_data
-
-
-def _auto_interpret(interpret: Optional[bool]) -> bool:
-    """Pallas runs natively on TPU only; anywhere else use interpret mode."""
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
 
 
 def _auto_use_kernel(use_kernel: Optional[bool]) -> bool:
@@ -257,7 +246,6 @@ def build_spmd_step(model, optimizer: opt_lib.Optimizer, mesh: Mesh, *,
                     num_workers: int, n_aggregate: int,
                     ema_decay: float = 0.0, clip_norm: float = 0.0,
                     use_kernel: Optional[bool] = None,
-                    interpret: Optional[bool] = None,
                     block: int = 4096, grad_batch: int = 0,
                     bucket_size: int = 0, model_cfg=None) -> Callable:
     """Mesh twin of ``train_step.build_train_step`` — same signature:
@@ -298,7 +286,6 @@ def build_spmd_step(model, optimizer: opt_lib.Optimizer, mesh: Mesh, *,
             f"'{WORKER_AXIS}' axis size ({mesh_data})")
     w_local = num_workers // mesh_data
     gb = validate_grad_batch(grad_batch, w_local)
-    interp = _auto_interpret(interpret)
     use_kernel = _auto_use_kernel(use_kernel)
     plan = resolve_tp(model_cfg, mesh)
     if plan.any:
@@ -359,7 +346,7 @@ def build_spmd_step(model, optimizer: opt_lib.Optimizer, mesh: Mesh, *,
         red, tail = reduce_then_psum(
             flat, mask, n_aggregate, axis_name=WORKER_AXIS,
             bucket=bucket_size, tail=tail, use_kernel=use_kernel,
-            interpret=interp, block=block)
+            block=block)
         agg = unflatten_vector(red, spec)
         sel = tail[0] / n_aggregate
         aux = tail[1] / num_workers

@@ -17,7 +17,7 @@ Two in-shard reduce implementations, selected by ``use_kernel``:
 
 * the ``kernels.backup_reduce`` Pallas kernel per bucket (one fused
   mask+scale+reduce pass over VMEM-streamed tiles; interpret mode
-  off-TPU), or
+  off-TPU, chosen from the backend), or
 * a jnp reference (``[W] @ [W, bucket]`` dot) — the oracle the property
   tests in ``tests/test_bucketed_reduce.py`` hold the kernel to.
 
@@ -68,7 +68,7 @@ def reduce_then_psum(grads: jnp.ndarray, mask: jnp.ndarray,
                      bucket: int = 0,
                      tail: Optional[jnp.ndarray] = None,
                      use_kernel: bool = True,
-                     interpret: bool = False,
+                     interpret: Optional[bool] = None,
                      block: int = 4096
                      ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """Bucketed masked reduce of [W, P] stacked grads, psum'd per bucket.

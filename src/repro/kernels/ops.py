@@ -1,14 +1,13 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False on real
-TPU backends — controlled by REPRO_PALLAS_INTERPRET or the platform.
-These wrappers also adapt layouts: models carry activations as
-[B, S, H, D]; the kernels want [B, H, S, D].
+The kernels run natively on TPU and in interpret mode on every other
+backend (``kernels.mode.interpret_mode``). These wrappers also adapt
+layouts: models carry activations as [B, S, H, D]; the kernels want
+[B, H, S, D].
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -16,13 +15,7 @@ import jax.numpy as jnp
 from repro.kernels import backup_reduce as _br
 from repro.kernels import flash_attention as _fa
 from repro.kernels import rwkv6_scan as _wk
-
-
-def _interpret_default() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+from repro.kernels.mode import interpret_mode
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",
@@ -35,7 +28,7 @@ def flash_attention_bshd(q, k, v, *, causal=True, window=0, softcap=0.0,
     vt = v.transpose(0, 2, 1, 3)
     out = _fa.flash_attention(qt, kt, vt, causal=causal, window=window,
                               softcap=softcap, block_q=block_q, block_k=block_k,
-                              interpret=_interpret_default())
+                              interpret=interpret_mode())
     return out.transpose(0, 2, 1, 3)
 
 
@@ -44,7 +37,7 @@ def wkv6(r, k, v, w, u, *, chunk=16):
     """r/k/v/w: [B, S, H, D]; u: [H, D] -> [B, S, H, D]."""
     args = [t.transpose(0, 2, 1, 3) for t in (r, k, v, w)]
     out = _wk.wkv6_chunked(*args, u, chunk=chunk,
-                           interpret=_interpret_default())
+                           interpret=interpret_mode())
     return out.transpose(0, 2, 1, 3)
 
 
@@ -52,4 +45,4 @@ def wkv6(r, k, v, w, u, *, chunk=16):
 def backup_reduce(grads, mask, n_aggregate, *, block=4096):
     """grads: [W, N]; mask: [W] -> [N] = (1/N_agg) * sum_selected."""
     return _br.backup_reduce(grads, mask, n_aggregate, block=block,
-                             interpret=_interpret_default())
+                             interpret=interpret_mode())

@@ -9,11 +9,14 @@ operand so the block index map can look up the physical page id before the
 body runs (``pltpu.PrefetchScalarGridSpec`` — see the quantization-kernel
 pattern in the Pallas guide). Dequantization of int8 pages fuses into the
 same pass: payload and scale blocks are gathered together and multiplied
-in VMEM, so the fp16 scales never round-trip through a separate gather.
+in VMEM, so the scales never round-trip through a separate gather. The
+pool stores scales as fp16, which Mosaic cannot load into a vector
+register; the kernel is handed them widened to f32 (exact), a pass over
+1/64 of the int8 payload's bytes.
 
 Like the other kernels in this package the Pallas path runs natively on
-TPU and under ``interpret=True`` elsewhere, and is parity-tested against
-the jnp twin (tests/test_serve_engine.py).
+TPU and in interpret mode elsewhere (``kernels.mode``), and is
+parity-tested against the jnp twin (tests/test_serve_engine.py).
 """
 from __future__ import annotations
 
@@ -23,11 +26,9 @@ import jax
 import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:                                       # TPU-specific grid spec
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:                        # pragma: no cover
-    pltpu = None
+from repro.kernels.mode import interpret_mode
 
 
 def gather_pages_reference(pool: jnp.ndarray, page_table: jnp.ndarray,
@@ -49,18 +50,15 @@ def _gather_kernel(tbl_ref, pool_ref, out_ref):
 
 
 def _gather_dequant_kernel(tbl_ref, pool_ref, scale_ref, out_ref):
-    deq = (pool_ref[0].astype(jnp.float32)
-           * scale_ref[0].astype(jnp.float32)[..., None])
+    deq = pool_ref[0].astype(jnp.float32) * scale_ref[0][..., None]
     out_ref[0, 0] = deq.astype(out_ref.dtype)
 
 
 def gather_pages_pallas(pool: jnp.ndarray, page_table: jnp.ndarray,
                         scales: Optional[jnp.ndarray] = None,
                         out_dtype=jnp.float32,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: Optional[bool] = None) -> jnp.ndarray:
     """Pallas page gather (+ fused int8 dequant when ``scales`` is given)."""
-    if pltpu is None:                      # pragma: no cover
-        return gather_pages_reference(pool, page_table, scales, out_dtype)
     b, maxp = page_table.shape
     _, ps, kv, hd = pool.shape
 
@@ -71,7 +69,7 @@ def gather_pages_pallas(pool: jnp.ndarray, page_table: jnp.ndarray,
     if scales is not None:
         in_specs.append(pl.BlockSpec((1, ps, kv),
                                      lambda i, p, tbl: (tbl[i, p], 0, 0)))
-        operands.append(scales)
+        operands.append(scales.astype(jnp.float32))
         kernel = _gather_dequant_kernel
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -84,7 +82,7 @@ def gather_pages_pallas(pool: jnp.ndarray, page_table: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, maxp, ps, kv, hd), out_dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(page_table, *operands)
     return out.reshape(b, maxp * ps, kv, hd)
 
@@ -92,7 +90,7 @@ def gather_pages_pallas(pool: jnp.ndarray, page_table: jnp.ndarray,
 def gather_pages(pool: jnp.ndarray, page_table: jnp.ndarray,
                  scales: Optional[jnp.ndarray] = None, *,
                  out_dtype=jnp.float32, use_kernel: bool = False,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: Optional[bool] = None) -> jnp.ndarray:
     if use_kernel:
         return gather_pages_pallas(pool, page_table, scales, out_dtype,
                                    interpret=interpret)

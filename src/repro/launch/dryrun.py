@@ -168,12 +168,8 @@ def parse_collectives(hlo_text: str) -> Dict[str, Any]:
 
 
 def cost_analysis(compiled) -> Dict[str, Any]:
-    """compiled.cost_analysis() across jax versions: older jax returns a
-    one-dict-per-device list, newer returns the dict directly."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
+    """``compiled.cost_analysis()``, ``{}`` where XLA reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def analyze(compiled, lower_s: float, compile_s: float) -> Dict[str, Any]:

@@ -1,4 +1,5 @@
-"""Production mesh builders + the platform / XLA-flag recipe.
+"""Production mesh builders, the platform / XLA-flag recipe and the
+compile-cache placement.
 
 Defined as FUNCTIONS so importing this module never touches jax device
 state (the dry-run sets XLA_FLAGS before any jax import; everything else
@@ -10,11 +11,11 @@ import os
 from typing import List, Optional
 
 import jax
+from jax.sharding import AxisType
 
-try:                                   # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:                    # older jax: Auto is the only mode
-    AxisType = None
+# the checkout root: the directory that holds src/
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.dirname(os.path.abspath(__file__)))))
 
 
 # The latency-hiding recipe (docs/spmd.md): async collectives + the
@@ -64,11 +65,25 @@ def set_platform(platform: str = "cpu", *,
     return enable_latency_hiding(platform=platform) if latency_hiding else []
 
 
+def init_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; returns its directory.
+
+    Called first thing by the CLI entry points (never on import). When
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it, and this
+    sets nothing. Otherwise the cache goes to the fixed
+    ``<checkout>/.jax_cache``, never a temporary or per-process name: a
+    directory that moved from run to run would never be found again.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(REPO_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def _mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -84,8 +99,5 @@ def make_host_mesh(data: int = 1, model: int = 1):
 
 
 def use_mesh(mesh):
-    """Context manager setting the ambient mesh, across jax versions:
-    jax.set_mesh where it exists, else the Mesh's own context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager setting the ambient mesh (``jax.set_mesh``)."""
+    return jax.set_mesh(mesh)

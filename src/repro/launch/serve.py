@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import configs
+from repro.launch.mesh import init_compile_cache
 from repro.models import get_model
 
 
@@ -218,6 +219,7 @@ def _router_main(args, engine, trace, tracer=None, metrics=None) -> None:
 
 
 def main(argv=None) -> None:
+    init_compile_cache()
     args = _build_parser().parse_args(argv)
     _validate(args)
     cfg = configs.get_smoke_config(args.arch)

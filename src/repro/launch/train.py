@@ -12,8 +12,8 @@
         --execution spmd --mesh-data 8 --chunk-size 8
 
 --smoke uses the reduced per-arch config (CPU-runnable); without it the
-full published config is built (TPU-scale — on this host use the dry-run
-instead). Everything routes through ``repro.train.loop.run_experiment``:
+full published config is built (TPU-scale: ``chip_smoke.py`` at the repo
+root drives it on one v5e chip). Everything routes through ``repro.train.loop.run_experiment``:
 mask strategies (backup/full_sync/timeout/dynamic_backup) drive the
 straggler simulator and the masked SPMD step; event strategies
 (async/softsync) drive the discrete-event parameter server — both with
@@ -38,6 +38,7 @@ from repro.configs.base import (AggregationConfig, CheckpointConfig,
                                 ExecutionConfig, FaultConfig, OptimizerConfig,
                                 ShapeConfig, TrainConfig)
 from repro.core.straggler import PaperCalibrated
+from repro.launch.mesh import init_compile_cache
 from repro.train.loop import run_experiment
 
 MASK_STRATEGIES = ("backup", "full_sync", "timeout", "dynamic_backup")
@@ -84,7 +85,7 @@ def build_config(args) -> TrainConfig:
                                   mesh_model=args.mesh_model or 1,
                                   grad_batch=args.grad_batch or 0,
                                   bucket_size=args.bucket_size or 0),
-        seed=args.seed, total_steps=args.steps, log_every=10,
+        seed=args.seed, total_steps=args.steps, log_every=args.log_every,
         chunk_size=args.chunk_size,
         straggler_backend=args.straggler_backend,
         prefetch_depth=args.prefetch_depth,
@@ -152,7 +153,10 @@ def _validate(ap: argparse.ArgumentParser, args) -> None:
                 ap.error(f"--grad-batch: {e}")
 
 
-def main(argv=None) -> None:
+def main(argv=None):
+    """Parse ``argv``, run the experiment, print its report and return
+    the :class:`~repro.train.loop.TrainResult`."""
+    init_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=configs.list_archs(),
                     default="qwen3-0.6b")
@@ -179,6 +183,9 @@ def main(argv=None) -> None:
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--log-every", type=int, default=10,
+                    help="record and print the metrics of every N-th step "
+                         "(and of the last)")
     ap.add_argument("--chunk-size", type=int, default=1,
                     help="iterations fused per device dispatch — SPMD steps "
                          "for mask strategies, PS updates for event "
@@ -298,6 +305,7 @@ def main(argv=None) -> None:
     if metrics is not None:
         metrics.dump_jsonl(args.metrics)
         print(f"[train] metrics: {args.metrics} ({len(metrics)} series)")
+    return res
 
 
 if __name__ == "__main__":

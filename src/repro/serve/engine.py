@@ -101,7 +101,7 @@ class ServeEngine:
                  page_size: int = 8, max_prompt_len: int = 32,
                  max_new_cap: int = 32, num_pages: Optional[int] = None,
                  cache_int8: bool = False, mesh_model: int = 1,
-                 use_kernel: bool = False, interpret: Optional[bool] = None,
+                 use_kernel: bool = False,
                  clock: str = "wall", step_time: float = 1.0,
                  prefill_time: float = 1.0, faults: Optional[str] = None,
                  fault_horizon: int = 256, fault_seed: int = 0,
@@ -114,7 +114,6 @@ class ServeEngine:
             raise ValueError(f"paged serving unsupported: {why}")
         if clock not in ("wall", "virtual"):
             raise ValueError(f"clock must be 'wall' or 'virtual' (got {clock})")
-        from repro.distributed.spmd_engine import _auto_interpret
         self.cfg = model_cfg
         self.model = get_model(model_cfg)
         # engine-level SLO admission (serve/slo.py SLOConfig): under
@@ -154,7 +153,6 @@ class ServeEngine:
             head_dim=model_cfg.resolved_head_dim,
             num_pages=num_pages, page_size=page_size, num_slots=num_slots,
             max_pages_per_slot=max_pages, quantized=cache_int8)
-        interp = _auto_interpret(interpret)
         self.mesh_model = mesh_model
         if mesh_model > 1:
             from repro.launch.mesh import make_host_mesh
@@ -168,7 +166,7 @@ class ServeEngine:
             template = jax.eval_shape(self.model.init, jax.random.PRNGKey(0))
             prefill, decode, plan, param_sh, pool_sh = build_tp_paged_fns(
                 model_cfg, self.mesh, template, quantized=cache_int8,
-                use_kernel=use_kernel, interpret=interp)
+                use_kernel=use_kernel)
             self.params = jax.tree_util.tree_map(
                 lambda x, s: jax.device_put(jnp.asarray(x), s),
                 params, param_sh)
@@ -176,8 +174,7 @@ class ServeEngine:
             self._pool_shardings = pool_sh
         else:
             decode = build_paged_decode(self.model, quantized=cache_int8,
-                                        use_kernel=use_kernel,
-                                        interpret=interp)
+                                        use_kernel=use_kernel)
             prefill = build_paged_prefill(self.model, quantized=cache_int8)
             self.params = jax.tree_util.tree_map(jnp.asarray, params)
             self.tp_plan = None

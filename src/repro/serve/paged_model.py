@@ -59,7 +59,7 @@ def supports_paged(cfg) -> Tuple[bool, str]:
 
 
 def _paged_attn(p_attn: Params, cfg, h, pool_l, lens, page_table, window, *,
-                quantized: bool, use_kernel: bool, interpret: bool):
+                quantized: bool, use_kernel: bool):
     """One layer's paged decode attention. h: [B, 1, d] (post-ln, post f)."""
     b = h.shape[0]
     kv, hd = cfg.num_kv_heads, cfg.resolved_head_dim
@@ -79,20 +79,18 @@ def _paged_attn(p_attn: Params, cfg, h, pool_l, lens, page_table, window, *,
         new_pool["k_scale"] = pool_l["k_scale"].at[pid, off].set(ksc[:, 0])
         new_pool["v_scale"] = pool_l["v_scale"].at[pid, off].set(vsc[:, 0])
         k = gather_pages(new_pool["k"], page_table, new_pool["k_scale"],
-                         out_dtype=h.dtype, use_kernel=use_kernel,
-                         interpret=interpret)
+                         out_dtype=h.dtype, use_kernel=use_kernel)
         v = gather_pages(new_pool["v"], page_table, new_pool["v_scale"],
-                         out_dtype=h.dtype, use_kernel=use_kernel,
-                         interpret=interpret)
+                         out_dtype=h.dtype, use_kernel=use_kernel)
     else:
         new_pool["k"] = pool_l["k"].at[pid, off].set(
             k_new[:, 0].astype(pool_l["k"].dtype))
         new_pool["v"] = pool_l["v"].at[pid, off].set(
             v_new[:, 0].astype(pool_l["v"].dtype))
         k = gather_pages(new_pool["k"], page_table, out_dtype=h.dtype,
-                         use_kernel=use_kernel, interpret=interpret)
+                         use_kernel=use_kernel)
         v = gather_pages(new_pool["v"], page_table, out_dtype=h.dtype,
-                         use_kernel=use_kernel, interpret=interpret)
+                         use_kernel=use_kernel)
     s = k.shape[1]                                        # max_pages * ps
     qg = q.reshape(b, kv, cfg.q_per_kv, hd)
     scores = jnp.einsum("bgqd,bsgd->bgqs", qg, k).astype(jnp.float32) \
@@ -119,7 +117,6 @@ def _ffn(p_l: Params, cfg, kind: str, h2):
 
 
 def build_paged_decode(model, *, quantized: bool, use_kernel: bool = False,
-                       interpret: bool = True,
                        gather_logits: Callable = None) -> Callable:
     """decode(params, state[B, 2+maxp], pool) -> (next_token [B], new pool).
 
@@ -147,8 +144,7 @@ def build_paged_decode(model, *, quantized: bool, use_kernel: bool = False,
                 h1 = tp.col_in(h1, "attn")
                 attn_out, pool_l = _paged_attn(
                     p_l["attn"], cfg, h1, pool_l, lens, page_table, win,
-                    quantized=quantized, use_kernel=use_kernel,
-                    interpret=interpret)
+                    quantized=quantized, use_kernel=use_kernel)
                 h = h + tp.row_out(attn_out, "attn")
                 h2 = common.rmsnorm(p_l["ln2"], h, cfg.norm_eps)
                 return h + _ffn(p_l, cfg, _kind, h2), pool_l
@@ -275,7 +271,7 @@ def tp_pool_specs(plan, quantized: bool) -> Dict[str, P]:
 
 
 def build_tp_paged_fns(model_cfg, mesh, params_template, *, quantized: bool,
-                       use_kernel: bool = False, interpret: bool = True):
+                       use_kernel: bool = False):
     """shard_map'd (prefill, decode) over the mesh 'model' axis.
 
     Params arrive FULL (gathered, as checkpoints are stored) and are
@@ -305,7 +301,6 @@ def build_tp_paged_fns(model_cfg, mesh, params_template, *, quantized: bool,
 
     decode_core = build_paged_decode(local_model, quantized=quantized,
                                      use_kernel=use_kernel,
-                                     interpret=interpret,
                                      gather_logits=gather_vocab)
     prefill_core = build_paged_prefill(local_model, quantized=quantized,
                                        gather_logits=gather_vocab)

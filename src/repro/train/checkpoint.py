@@ -54,6 +54,19 @@ def _flatten_with_paths(tree: Any) -> Dict[str, np.ndarray]:
     return flat
 
 
+def _to_storable(flat: Dict[str, np.ndarray]) -> Dict[str, str]:
+    """npz keeps numpy's own dtypes only: an extension dtype such as
+    bfloat16 comes back as raw void bytes. Such arrays are stored as
+    same-width unsigned ints (in place in ``flat``); returns their real
+    dtype names, which the manifest keeps for the load to view back."""
+    dtypes = {}
+    for k, v in flat.items():
+        if v.dtype.kind == "V":
+            dtypes[k] = v.dtype.name
+            flat[k] = v.view(f"u{v.dtype.itemsize}")
+    return dtypes
+
+
 def _unflatten_like(template: Any, flat: Dict[str, np.ndarray]) -> Any:
     paths_and_leaves, treedef = jax.tree_util.tree_flatten_with_path(template)
     leaves = []
@@ -139,8 +152,10 @@ def save(directory: str, step: int, tree: Any, metadata: Optional[Dict] = None,
     os.makedirs(directory, exist_ok=True)
     final = os.path.join(directory, f"step_{step:08d}")
     flat = _flatten_with_paths(tree)
+    dtypes = _to_storable(flat)
     manifest = {"step": step, "arrays": sorted(flat),
                 "checksums": {k: _checksum(v) for k, v in flat.items()},
+                **({"dtypes": dtypes} if dtypes else {}),
                 **(metadata or {})}
     delays = retry_delays(retries, backoff_s, max_backoff_s=max_backoff_s,
                           jitter=jitter, seed=backoff_seed)
@@ -248,6 +263,8 @@ def _load_verified(directory: str, step: int
             raise CheckpointCorruption(
                 f"step {step}: checksum mismatch for {k!r} "
                 f"({got} != manifest {want})")
+    for k, name in manifest.get("dtypes", {}).items():
+        flat[k] = flat[k].view(np.dtype(name))
     return flat, manifest
 
 

@@ -263,7 +263,6 @@ class Trainer:
             # override has no config, so the axis stays replicated there
             engine_kwargs = dict(step_kwargs,
                                  use_kernel=cfg.execution.use_kernel,
-                                 interpret=cfg.execution.interpret,
                                  grad_batch=cfg.execution.grad_batch,
                                  bucket_size=cfg.execution.bucket_size,
                                  model_cfg=(None if self._model_override
@@ -271,6 +270,10 @@ class Trainer:
             engine_tracer = (self.tracer
                              if getattr(self, "tracer", None) is not None
                              and self.tracer.enabled else None)
+            self._state_shardings = spmd_engine.state_shardings(
+                self.model, self.optimizer, self.mesh,
+                ema_decay=cfg.optimizer.ema_decay,
+                model_cfg=engine_kwargs["model_cfg"])
             self.train_step = spmd_engine.make_train_step(
                 self.model, self.optimizer, self.mesh,
                 tracer=engine_tracer, **engine_kwargs)
@@ -365,10 +368,21 @@ class Trainer:
 
     def init_state(self, seed: Optional[int] = None) -> None:
         key = jax.random.PRNGKey(self.cfg.seed if seed is None else seed)
-        self.params = self.model.init(key)
-        self.opt_state = self.optimizer.init(self.params)
-        self.ema = (ema_lib.init(self.params)
-                    if self.cfg.optimizer.ema_decay > 0 else None)
+        ema_on = self.cfg.optimizer.ema_decay > 0
+        if self._spmd:
+            # born on the engine's mesh shardings: built on one device and
+            # resharded by the first step, a full-width state would sit on
+            # that device twice over
+            psh, osh, esh = self._state_shardings
+            self.params = jax.jit(self.model.init, out_shardings=psh)(key)
+            self.opt_state = jax.jit(self.optimizer.init,
+                                     out_shardings=osh)(self.params)
+            self.ema = (jax.jit(ema_lib.init, out_shardings=esh)(self.params)
+                        if ema_on else None)
+        else:
+            self.params = self.model.init(key)
+            self.opt_state = self.optimizer.init(self.params)
+            self.ema = ema_lib.init(self.params) if ema_on else None
         if self.strategy.kind == "event":
             self._init_event_state()
 

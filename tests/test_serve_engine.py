@@ -176,7 +176,7 @@ def test_engine_kernel_path_matches_reference(qwen, qwen_engine):
     cfg, _, params = qwen
     eng_k = ServeEngine(cfg, params, num_slots=3, page_size=4,
                         max_prompt_len=12, max_new_cap=8, clock="virtual",
-                        use_kernel=True, interpret=True)
+                        use_kernel=True)
     trace = _trace(3, vocab=cfg.vocab_size)
     assert eng_k.run(trace).tokens_by_rid() == \
         qwen_engine.run(trace).tokens_by_rid()

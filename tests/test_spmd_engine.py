@@ -571,24 +571,31 @@ def test_spmd_regression_guard(tmp_path):
                   "spmd_vs_sim_w16_chunk64_m4": 0.9}) == 0
 
 
-def test_bench_run_forwards_flags(monkeypatch, tmp_path):
-    """bench_spmd.run() re-execs itself in a fresh subprocess (the forced
-    device count must precede jax init); trace/metrics/platform requests
-    must survive that hop — forwarded from env to the child's argv."""
+def _import_bench_spmd():
+    """Import benchmarks/bench_spmd.py, undoing its import-time forced
+    host-device XLA_FLAGS so later in-process tests are unaffected."""
     import importlib
     import os as _os
-    import subprocess as _sp
     import sys as _sys
 
     saved = _os.environ.get("XLA_FLAGS")
     _sys.path.insert(0, str(_ROOT / "benchmarks"))
     try:
-        bench_spmd = importlib.import_module("bench_spmd")
+        return importlib.import_module("bench_spmd")
     finally:
         if saved is None:
             _os.environ.pop("XLA_FLAGS", None)
         else:
             _os.environ["XLA_FLAGS"] = saved
+
+
+def test_bench_run_forwards_flags(monkeypatch, tmp_path):
+    """bench_spmd.run() re-execs itself in a fresh subprocess (the forced
+    device count must precede jax init); trace/metrics/platform requests
+    must survive that hop — forwarded from env to the child's argv."""
+    import subprocess as _sp
+
+    bench_spmd = _import_bench_spmd()
     calls = []
 
     def fake_run(cmd, **kw):
@@ -607,6 +614,36 @@ def test_bench_run_forwards_flags(monkeypatch, tmp_path):
     assert cmd[cmd.index("--platform") + 1] == "cpu"
     # rows come from the committed BENCH payload (the child was faked)
     assert any(name.startswith("spmd.spmd_vs_sim") for name, _, _ in rows)
+
+
+def test_bench_run_in_process_on_accelerator(monkeypatch):
+    """On a non-CPU backend the harness process holds the chips, so
+    run() starts no child: it runs main() in-process on the real devices
+    when there are enough, and otherwise fails naming both counts."""
+    import jax
+
+    bench_spmd = _import_bench_spmd()
+
+    def no_child(cmd, **kw):
+        raise AssertionError(f"started a child on an accelerator: {cmd}")
+
+    monkeypatch.setattr(bench_spmd.subprocess, "run", no_child)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "device_count", lambda: 4)
+    with pytest.raises(RuntimeError, match="needs 16 tpu devices.*has 4"):
+        bench_spmd.run(quick=True)
+
+    argvs = []
+    payload = {"results": [{"backend": "spmd", "workers": 8,
+                            "chunk_size": 1, "mesh_model": 2,
+                            "steps_per_s": 10.0}],
+               "spmd_vs_sim_w8_chunk1_m2": 0.5}
+    monkeypatch.setattr(jax, "device_count", lambda: 16)
+    monkeypatch.setattr(bench_spmd, "main",
+                        lambda argv: argvs.append(argv) or payload)
+    rows = bench_spmd.run(quick=True)
+    assert argvs == [["--quick"]]
+    assert ("spmd.spmd_w8_chunk1_m2", 1e5, "10.0steps/s") in rows
 
 
 # ---------------------------------------------------------------------------
