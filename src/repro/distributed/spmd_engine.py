@@ -308,8 +308,9 @@ def build_spmd_step(model, optimizer: opt_lib.Optimizer, mesh: Mesh, *,
         shards = jax.tree_util.tree_map(reshape, batch)
 
         def one_worker(worker_batch):
-            (_, (mean_loss, aux)), g = jax.value_and_grad(
-                worker_loss, has_aux=True)(params, worker_batch)
+            with jax.named_scope("grad"):
+                (_, (mean_loss, aux)), g = jax.value_and_grad(
+                    worker_loss, has_aux=True)(params, worker_batch)
             return g, mean_loss, aux
 
         # per-worker gradients, batched per grad_batch: the full vmap is
@@ -341,12 +342,14 @@ def build_spmd_step(model, optimizer: opt_lib.Optimizer, mesh: Mesh, *,
         # (ONE by default) cover Alg. 4 line 7 plus the metrics. Losses
         # are replicated over 'model' (the CE ends in psums), so only
         # the 'data' reduction is collective.
-        flat, spec = flatten_stacked(grads)         # [W_local, P_local] f32
+        with jax.named_scope("grad_stack"):
+            flat, spec = flatten_stacked(grads)     # [W_local, P_local] f32
         tail = jnp.stack([jnp.sum(losses * mf), jnp.sum(auxes)])
-        red, tail = reduce_then_psum(
-            flat, mask, n_aggregate, axis_name=WORKER_AXIS,
-            bucket=bucket_size, tail=tail, use_kernel=use_kernel,
-            block=block)
+        with jax.named_scope("reduce"):
+            red, tail = reduce_then_psum(
+                flat, mask, n_aggregate, axis_name=WORKER_AXIS,
+                bucket=bucket_size, tail=tail, use_kernel=use_kernel,
+                block=block)
         agg = unflatten_vector(red, spec)
         sel = tail[0] / n_aggregate
         aux = tail[1] / num_workers
@@ -361,16 +364,18 @@ def build_spmd_step(model, optimizer: opt_lib.Optimizer, mesh: Mesh, *,
         grads, sel, aux = mapped(batch, mask, params)
         frac = jnp.sum(mask.astype(jnp.float32)) / n_aggregate
         metrics = {"loss": sel / jnp.maximum(frac, 1e-6), "aux_loss": aux}
-        if clip_norm > 0:
-            # global_norm sums over all leaves; on sharded trees GSPMD
-            # lowers the per-leaf reductions to one small all-reduce
-            grads, gnorm = opt_lib.clip_by_global_norm(grads, clip_norm)
-            metrics["grad_norm"] = gnorm
-        new_params, new_opt, stats = optimizer.apply(params, grads,
-                                                     opt_state, step)
+        with jax.named_scope("optimizer"):
+            if clip_norm > 0:
+                # global_norm sums over all leaves; on sharded trees GSPMD
+                # lowers the per-leaf reductions to one small all-reduce
+                grads, gnorm = opt_lib.clip_by_global_norm(grads, clip_norm)
+                metrics["grad_norm"] = gnorm
+            new_params, new_opt, stats = optimizer.apply(params, grads,
+                                                         opt_state, step)
         metrics.update(stats)
         if ema_decay > 0:
-            ema_state = ema_lib.update(ema_state, new_params, ema_decay)
+            with jax.named_scope("ema"):
+                ema_state = ema_lib.update(ema_state, new_params, ema_decay)
         return new_params, new_opt, ema_state, metrics
 
     return step_fn
@@ -447,22 +452,7 @@ def state_shardings(model, optimizer, mesh: Mesh, *, ema_decay: float = 0.0,
     return psh, osh, esh
 
 
-
-def _traced(fn, tracer) -> Callable:
-    """Bracket a jitted mesh step with spmd/dispatch + collective-wait
-    spans. Only installed when a live tracer is passed: the fence
-    (``block_until_ready``) serializes dispatch against device work, so
-    the untraced path must keep the bare async-dispatch callable."""
-    def call(*args):
-        with tracer.span("spmd/dispatch"):
-            out = fn(*args)
-        with tracer.span("spmd/collective_wait"):
-            jax.block_until_ready(out)
-        return out
-    return call
-
-
-def make_train_step(model, optimizer, mesh: Mesh, *, tracer=None,
+def make_train_step(model, optimizer, mesh: Mesh,
                     **step_kwargs) -> Callable:
     """Jitted per-step engine, drop-in for the Trainer's ``train_step``:
     step/mask replicated, batch rows sharded over 'data', and params/
@@ -475,15 +465,13 @@ def make_train_step(model, optimizer, mesh: Mesh, *, tracer=None,
         model_cfg=step_kwargs.get("model_cfg"))
     rep = _replicated(mesh)
     bsh = NamedSharding(mesh, P(WORKER_AXIS))
-    fn = jax.jit(build_spmd_step(model, optimizer, mesh, **step_kwargs),
-                 in_shardings=(psh, osh, esh, rep, bsh, rep),
-                 out_shardings=(psh, osh, esh, rep),
-                 donate_argnums=(0, 1, 2))
-    return _traced(fn, tracer) if tracer is not None and tracer.enabled \
-        else fn
+    return jax.jit(build_spmd_step(model, optimizer, mesh, **step_kwargs),
+                   in_shardings=(psh, osh, esh, rep, bsh, rep),
+                   out_shardings=(psh, osh, esh, rep),
+                   donate_argnums=(0, 1, 2))
 
 
-def make_chunk_step(model, optimizer, mesh: Mesh, *, tracer=None,
+def make_chunk_step(model, optimizer, mesh: Mesh,
                     **step_kwargs) -> Callable:
     """Jitted K-step engine, drop-in for the Trainer's ``chunk_step``:
     stacked batches [K, B, ...] shard axis 1 (the batch rows) over 'data';
@@ -494,10 +482,8 @@ def make_chunk_step(model, optimizer, mesh: Mesh, *, tracer=None,
         model_cfg=step_kwargs.get("model_cfg"))
     rep = _replicated(mesh)
     bsh = NamedSharding(mesh, P(None, WORKER_AXIS))
-    fn = jax.jit(
+    return jax.jit(
         build_spmd_chunk_step(model, optimizer, mesh, **step_kwargs),
         in_shardings=(psh, osh, esh, rep, bsh, rep),
         out_shardings=(psh, osh, esh, rep),
         donate_argnums=(0, 1, 2))
-    return _traced(fn, tracer) if tracer is not None and tracer.enabled \
-        else fn

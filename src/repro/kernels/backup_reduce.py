@@ -58,5 +58,6 @@ def backup_reduce(grads: jnp.ndarray, mask: jnp.ndarray, n_aggregate: int, *,
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((padded,), jnp.float32),
         interpret=interpret_mode(interpret),
+        name="backup_reduce",           # the kernel's name on a device trace
     )(grads, mask.astype(jnp.float32).reshape(w, 1))
     return out[:n] if pad else out

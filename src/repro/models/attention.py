@@ -104,12 +104,15 @@ def gqa_attend(params: Params, cfg, x: jnp.ndarray, positions: jnp.ndarray,
     k = _expand_kv(k, cfg.q_per_kv)
     v = _expand_kv(v, cfg.q_per_kv)
     hd = cfg.resolved_head_dim
-    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / math.sqrt(hd)
-    scores = common.softcap(scores, cfg.attn_logit_softcap)
-    mask = make_attention_mask(s, s, window=window)
-    scores = jnp.where(mask[None, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-    out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    # the attention core, scores to weighted values (what a flash kernel
+    # replaces), named on the device trace; the projections stay outside
+    with jax.named_scope("attention"):
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) / math.sqrt(hd)
+        scores = common.softcap(scores, cfg.attn_logit_softcap)
+        mask = make_attention_mask(s, s, window=window)
+        scores = jnp.where(mask[None, None], scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+        out = jnp.einsum("bhqk,bkhd->bqhd", probs, v)
     return common.dense(params["wo"], out.reshape(b, s, -1))
 
 
@@ -199,9 +202,10 @@ def gqa_attend_chunked(params: Params, cfg, x: jnp.ndarray, positions: jnp.ndarr
     q, k, v = _project_qkv(params, cfg, x, positions)
     k = _expand_kv(k, cfg.q_per_kv)
     v = _expand_kv(v, cfg.q_per_kv)
-    out = chunked_attention_core(q, k, v, causal=True, window=window,
-                                 softcap=cfg.attn_logit_softcap,
-                                 q_chunk=q_chunk, kv_chunk=kv_chunk)
+    with jax.named_scope("attention"):          # as in gqa_attend
+        out = chunked_attention_core(q, k, v, causal=True, window=window,
+                                     softcap=cfg.attn_logit_softcap,
+                                     q_chunk=q_chunk, kv_chunk=kv_chunk)
     return common.dense(params["wo"], out.reshape(b, s, -1))
 
 

@@ -1,10 +1,11 @@
 """Observability: tracing + metrics + measured latency (docs/observability.md).
 
-Zero-dependency (numpy + stdlib) and at the bottom of the layer order:
-``core``, ``distributed``, ``serve`` and ``train`` all import ``obs``,
-never the reverse. The disabled path is free — pass ``tracer=None``
-anywhere and :func:`as_tracer` substitutes the shared no-op
-:data:`NULL` tracer.
+Numpy, the stdlib and ``jax.profiler`` only, and at the bottom of the
+layer order: ``core``, ``distributed``, ``serve`` and ``train`` all
+import ``obs``, never the reverse. The disabled path records nothing —
+pass ``tracer=None`` anywhere and :func:`as_tracer` substitutes the
+no-op :data:`NULL` tracer, whose spans are only profiler annotations
+(``repro.obs.profile`` reads them back from a profile).
 """
 from repro.obs.latency import EmpiricalLatencyModel
 from repro.obs.metrics import (

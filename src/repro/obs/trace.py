@@ -1,7 +1,15 @@
-"""Host-side tracer: nested spans, ring-buffered, Chrome-trace export.
+"""Host-side tracer: nested spans on the profiler's clock, ring-buffered,
+Chrome-trace export.
 
 The measurement substrate of the telemetry layer (docs/observability.md).
-A :class:`Tracer` records *host wall-clock* spans via
+Every span, with a :class:`Tracer` or without one, enters a
+``jax.profiler.TraceAnnotation`` of the same name: when a profile of the
+program is taken (``jax.profiler.trace``), the loop's spans land on the
+host plane beside the device planes, on one clock, so a gap on a chip can
+be put down to the host span that held it. The annotation carries the
+name only; a span's ``args`` stay in the tracer's ring.
+
+A :class:`Tracer` additionally records *host wall-clock* spans via
 ``time.perf_counter_ns``; device work is bracketed by the callers with
 ``jax.block_until_ready`` fences **at chunk edges only**, so the fused
 ``lax.scan`` hot loop is never broken into per-step dispatches just to
@@ -9,15 +17,15 @@ be observable. Events live in a bounded ring (old events drop, the
 ``dropped`` counter records how many) and export as Chrome-trace JSON —
 load the file at https://ui.perfetto.dev or chrome://tracing.
 
-Disabled tracing must cost nothing: pass no tracer and every
-instrumentation site sees :data:`NULL` — a singleton whose ``span()``
-returns one shared no-op context manager (no allocation, no clock
-read). The overhead test in ``tests/test_obs.py`` holds the no-op path
-under 2% of the chunked training loop.
+Disabled tracing records nothing and fences nothing: pass no tracer and
+every instrumentation site sees :data:`NULL`, whose ``span()`` is one
+inactive ``TraceAnnotation`` (~0.6 µs when no profiler runs). The
+overhead test in ``tests/test_obs.py`` holds that path under 2% of the
+chunked training loop.
 
 Span names are registered in :data:`SPAN_NAMES`; the docs drift guard
 (``tests/test_docs.py``) keeps every name documented in
-docs/observability.md. Zero dependencies: stdlib only.
+docs/observability.md. Dependencies: the stdlib and ``jax.profiler``.
 """
 from __future__ import annotations
 
@@ -26,18 +34,20 @@ import json
 import time
 from typing import Any, Deque, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 # The span taxonomy: every name an instrumentation site emits. cat is
 # the prefix; the drift guard pins each name into docs/observability.md.
 SPAN_NAMES = (
     # train/loop.py
     "train/step",             # legacy per-step dispatch (chunk_size=1)
     "train/chunk",            # one fused K-step lax.scan dispatch
+    "train/select",           # arrival draw + the N-of-N+b mask
+    "train/data_wait",        # prefetcher / batch staging and upload
+    "train/dispatch",         # the jitted step or chunk call
     "train/device_wait",      # block_until_ready fence at the chunk edge
-    "train/data_wait",        # prefetcher / batch staging
+    "train/metrics_sync",     # readback of a logged step's metrics
     "train/ckpt_save",        # atomic checkpoint commit
-    # distributed/spmd_engine.py
-    "spmd/dispatch",          # jitted mesh step/chunk call (all shards)
-    "spmd/collective_wait",   # block_until_ready: collectives + compute
     # serve/engine.py (+ StepSession)
     "serve/admit",            # admission: slot+pages grant, incl. prefill
     "serve/prefill",          # the jitted bucketed prefill call
@@ -51,30 +61,15 @@ SPAN_NAMES = (
 )
 
 
-class _NullSpan:
-    """Shared no-op context manager — the disabled-tracing fast path."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class NullTracer:
-    """Tracing disabled: every method is a no-op, ``span()`` allocates
-    nothing (returns one shared context manager)."""
+    """Tracing disabled: nothing is recorded and nothing fenced; ``span()``
+    is only the profiler annotation, inactive unless a profile is taken."""
 
     __slots__ = ()
     enabled = False
 
-    def span(self, name: str, cat: str = "", **args) -> _NullSpan:
-        return _NULL_SPAN
+    def span(self, name: str, cat: str = "", **args) -> TraceAnnotation:
+        return TraceAnnotation(name)
 
     def instant(self, name: str, cat: str = "", **args) -> None:
         pass
@@ -95,9 +90,10 @@ def as_tracer(tracer) -> Any:
 
 
 class _Span:
-    """One live span: ``with tracer.span(...):`` emits an "X" event."""
+    """One live span: ``with tracer.span(...):`` emits an "X" event and
+    holds the profiler annotation of the same name."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_start")
+    __slots__ = ("_tracer", "name", "cat", "args", "_start", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: Dict):
         self._tracer = tracer
@@ -106,11 +102,14 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self._start = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         end = time.perf_counter_ns()
+        self._annotation.__exit__(*exc)
         tr = self._tracer
         tr._emit({"name": self.name, "cat": self.cat, "ph": "X",
                   "ts": (self._start - tr._t0) / 1e3,

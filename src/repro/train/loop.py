@@ -146,14 +146,18 @@ class Trainer:
         ``injector`` attaches a chaos-engine fault plan (repro.core.faults);
         the supervisor owns it across restarts so faults fire at most once.
 
-        ``tracer`` (repro.obs.Tracer) records train/chunk, train/step,
-        train/data_wait, train/device_wait and train/ckpt_save spans;
-        ``metrics`` (repro.obs.MetricsRegistry) accumulates the train/*
-        schema. Either being set — or the strategy running with
-        ``latency_source='measured'`` — turns on block_until_ready
-        fences at chunk edges (never inside the fused scan), so chunk
-        timings are real; with both unset the loop is untouched (the
-        no-op tracer path, held under 2%% overhead by tests/test_obs.py).
+        The loop's spans (train/step or train/chunk, and inside it
+        train/select, train/data_wait, train/dispatch, train/metrics_sync;
+        train/ckpt_save) always enter a ``jax.profiler.TraceAnnotation``,
+        so any profile taken of the program shows them on the device
+        clock. ``tracer`` (repro.obs.Tracer) also records them, with
+        train/device_wait; ``metrics`` (repro.obs.MetricsRegistry)
+        accumulates the train/* schema. Either being set — or the
+        strategy running with ``latency_source='measured'`` — turns on
+        block_until_ready fences at chunk edges (never inside the fused
+        scan), so chunk timings are real; with both unset the loop
+        records nothing and fences nothing (the no-op tracer path, held
+        under 2%% overhead by tests/test_obs.py).
         """
         self.cfg = cfg
         self.latency = latency or PaperCalibrated()
@@ -267,20 +271,15 @@ class Trainer:
                                  bucket_size=cfg.execution.bucket_size,
                                  model_cfg=(None if self._model_override
                                             else cfg.model))
-            engine_tracer = (self.tracer
-                             if getattr(self, "tracer", None) is not None
-                             and self.tracer.enabled else None)
             self._state_shardings = spmd_engine.state_shardings(
                 self.model, self.optimizer, self.mesh,
                 ema_decay=cfg.optimizer.ema_decay,
                 model_cfg=engine_kwargs["model_cfg"])
             self.train_step = spmd_engine.make_train_step(
-                self.model, self.optimizer, self.mesh,
-                tracer=engine_tracer, **engine_kwargs)
+                self.model, self.optimizer, self.mesh, **engine_kwargs)
             if cfg.chunk_size > 1:
                 self.chunk_step = spmd_engine.make_chunk_step(
-                    self.model, self.optimizer, self.mesh,
-                    tracer=engine_tracer, **engine_kwargs)
+                    self.model, self.optimizer, self.mesh, **engine_kwargs)
                 self.prefetcher = ChunkPrefetcher(
                     self.pipeline.cfg, depth=cfg.prefetch_depth)
             self.step = 0
@@ -899,30 +898,35 @@ class Trainer:
     def _run_one_step(self, target: int) -> None:
         """Legacy per-step path: one dispatch + one metrics sync per step."""
         t0 = self._now()
-        with self.tracer.span("train/step", step=int(self.step)):
-            td0 = self._now()
-            with self.tracer.span("train/data_wait"):
+        span = self.tracer.span
+        with span("train/step", step=int(self.step)):
+            with span("train/select"):
                 ev = self.sim.next_event()
+                mask = jnp.asarray(ev.mask)
+            td0 = self._now()
+            with span("train/data_wait"):
                 batch_np = self.pipeline.next()
                 batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
             data_s = time.perf_counter() - td0 if td0 is not None else 0.0
-            mask = jnp.asarray(ev.mask)
-            self.params, self.opt_state, self.ema, m = self.train_step(
-                self.params, self.opt_state, self.ema,
-                jnp.asarray(self.step, jnp.int32), batch, mask)
+            with span("train/dispatch"):
+                self.params, self.opt_state, self.ema, m = self.train_step(
+                    self.params, self.opt_state, self.ema,
+                    jnp.asarray(self.step, jnp.int32), batch, mask)
             if self._obs:
                 self._fence()
-        self._observe_chunk(1, t0, data_s)
-        self.sim_time += ev.iteration_time
-        self.step += 1
-        selected = int(ev.mask.sum())
-        self._sel_sum += selected
-        self._sel_count += 1
-        if self.step % self.cfg.log_every == 0 or self.step == target:
-            rec = {"step": self.step, "sim_time": self.sim_time,
-                   "selected": selected, "staleness": 0.0,
-                   **{k: float(v) for k, v in m.items()}}
-            self.metrics.append(rec)
+            self._observe_chunk(1, t0, data_s)
+            self.sim_time += ev.iteration_time
+            self.step += 1
+            selected = int(ev.mask.sum())
+            self._sel_sum += selected
+            self._sel_count += 1
+            if self.step % self.cfg.log_every == 0 or self.step == target:
+                # the host waits here on the device (the step's metrics)
+                with span("train/metrics_sync"):
+                    rec = {"step": self.step, "sim_time": self.sim_time,
+                           "selected": selected, "staleness": 0.0,
+                           **{k: float(v) for k, v in m.items()}}
+                self.metrics.append(rec)
 
     def _run_chunk(self, k: int, target: int,
                    kill_worker_at: Dict[int, int]) -> None:
@@ -930,26 +934,29 @@ class Trainer:
         step0 = jnp.asarray(self.step, jnp.int32)
         t0 = self._now()
         data_s = 0.0
-        if self.cfg.straggler_backend == "device":
-            # fully device-resident: batches, arrivals and masks are all
-            # produced inside the scan body — no per-chunk host transfer
-            with self.tracer.span("train/chunk", k=k, step=int(self.step)):
+        span = self.tracer.span
+        with span("train/chunk", k=k, step=int(self.step)):
+            if self.cfg.straggler_backend == "device":
+                # fully device-resident: batches, arrivals and masks are
+                # all produced inside the scan body — no per-chunk host
+                # transfer
                 self.pipeline.state.step += k
-                dead = jnp.asarray(self.sim.dead)
-                (self.params, self.opt_state, self.ema, ms, masks_dev,
-                 times_dev) = self.chunk_step_device(
-                    self.params, self.opt_state, self.ema, step0, k,
-                    dead, self._chunk_key)
+                with span("train/dispatch"):
+                    dead = jnp.asarray(self.sim.dead)
+                    (self.params, self.opt_state, self.ema, ms, masks_dev,
+                     times_dev) = self.chunk_step_device(
+                        self.params, self.opt_state, self.ema, step0, k,
+                        dead, self._chunk_key)
                 if self._obs:
                     self._fence()
-            masks = masks_dev                 # converted lazily iff logging
-            times = np.asarray(times_dev, np.float64)
-            self._sel_sum += float(jnp.sum(masks_dev))
-            self.sim.reset_to_step(self.sim.step + k)
-        else:
-            with self.tracer.span("train/chunk", k=k, step=int(self.step)):
+                with span("train/metrics_sync"):
+                    masks = masks_dev         # converted lazily iff logging
+                    times = np.asarray(times_dev, np.float64)
+                    self._sel_sum += float(jnp.sum(masks_dev))
+                self.sim.reset_to_step(self.sim.step + k)
+            else:
                 td0 = self._now()
-                with self.tracer.span("train/data_wait"):
+                with span("train/data_wait"):
                     chunk_np = self.prefetcher.get(
                         self.pipeline.state.step, k,
                         next_specs=self._next_chunk_specs(k, target,
@@ -959,25 +966,29 @@ class Trainer:
                                for key, v in chunk_np.items()}
                 data_s = (time.perf_counter() - td0
                           if td0 is not None else 0.0)
-                events = self.sim.next_events(k)
-                masks = events.masks
-                times = events.times
-                self._sel_sum += float(masks.sum())
-                self.params, self.opt_state, self.ema, ms = self.chunk_step(
-                    self.params, self.opt_state, self.ema, step0, batches,
-                    jnp.asarray(masks))
+                with span("train/select"):
+                    events = self.sim.next_events(k)
+                    masks = events.masks
+                    times = events.times
+                    self._sel_sum += float(masks.sum())
+                    masks_in = jnp.asarray(masks)
+                with span("train/dispatch"):
+                    self.params, self.opt_state, self.ema, ms = \
+                        self.chunk_step(self.params, self.opt_state,
+                                        self.ema, step0, batches, masks_in)
                 if self._obs:
                     self._fence()
-        self._observe_chunk(k, t0, data_s)
+            self._observe_chunk(k, t0, data_s)
+            # metrics sync only when a log record falls inside this chunk
+            logged = [i for i in range(k)
+                      if (self.step + i + 1) % self.cfg.log_every == 0
+                      or (self.step + i + 1) == target]
+            if logged:
+                with span("train/metrics_sync"):
+                    if not isinstance(masks, np.ndarray):
+                        masks = np.asarray(masks)
+                    ms_np = {key: np.asarray(v) for key, v in ms.items()}
         self._sel_count += k
-        # metrics sync only when a log record falls inside this chunk
-        logged = [i for i in range(k)
-                  if (self.step + i + 1) % self.cfg.log_every == 0
-                  or (self.step + i + 1) == target]
-        if logged:
-            if not isinstance(masks, np.ndarray):
-                masks = np.asarray(masks)
-            ms_np = {key: np.asarray(v) for key, v in ms.items()}
         for i in range(k):
             self.sim_time += float(times[i])
             self.step += 1

@@ -111,14 +111,20 @@ def build_train_step(model, optimizer: opt_lib.Optimizer, *, num_workers: int,
         return grads, metrics
 
     def train_step(params, opt_state, ema_state, step, batch, mask):
-        grads, metrics = compute_grads(params, batch, mask)
-        if clip_norm > 0:
-            grads, gnorm = opt_lib.clip_by_global_norm(grads, clip_norm)
-            metrics["grad_norm"] = gnorm
-        new_params, new_opt, stats = optimizer.apply(params, grads, opt_state, step)
+        # named scopes are HLO metadata only (the profiler's op_name):
+        # they name the step's phases on a device trace and change no op
+        with jax.named_scope("grad"):
+            grads, metrics = compute_grads(params, batch, mask)
+        with jax.named_scope("optimizer"):
+            if clip_norm > 0:
+                grads, gnorm = opt_lib.clip_by_global_norm(grads, clip_norm)
+                metrics["grad_norm"] = gnorm
+            new_params, new_opt, stats = optimizer.apply(params, grads,
+                                                         opt_state, step)
         metrics.update(stats)
         if ema_decay > 0:
-            ema_state = ema_lib.update(ema_state, new_params, ema_decay)
+            with jax.named_scope("ema"):
+                ema_state = ema_lib.update(ema_state, new_params, ema_decay)
         return new_params, new_opt, ema_state, metrics
 
     return train_step

@@ -121,11 +121,16 @@ def test_load_trace_rejects_malformed(tmp_path):
 
 
 def test_null_tracer_is_shared_noop():
+    import jax
     assert as_tracer(None) is NULL and not NULL.enabled
     s1, s2 = NULL.span("train/chunk", k=1), NULL.span("serve/decode")
-    assert s1 is s2                                # no per-call allocation
+    # a null span is only the profiler annotation of its name: it keeps
+    # no args and records nothing (the tracer has no state at all)
+    for s in (s1, s2):
+        assert type(s) is jax.profiler.TraceAnnotation
     with s1:
         pass
+    assert not hasattr(NULL, "__dict__") and NULL.__slots__ == ()
     NULL.instant("router/timeout")
     NULL.export("/nonexistent/dir/never_written.json")
 
@@ -331,7 +336,9 @@ def test_null_path_overhead_under_two_percent(tmp_path):
         with NULL.span("train/chunk"):
             pass
     hook_s = (time.perf_counter() - t0) / n
-    hooks_per_chunk = 5        # chunk + data_wait + device_wait + 2 clock
+    # chunk + data_wait + select + dispatch + metrics_sync, + 2 clock
+    # reads (no device_wait: the untraced loop fences nothing)
+    hooks_per_chunk = 7
     overhead = hooks_per_chunk * hook_s / chunk_s
     assert overhead < 0.02, (
         f"no-op tracing hooks cost {overhead:.2%} of a chunk "
