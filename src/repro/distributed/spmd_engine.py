@@ -246,7 +246,7 @@ def build_spmd_step(model, optimizer: opt_lib.Optimizer, mesh: Mesh, *,
                     num_workers: int, n_aggregate: int,
                     ema_decay: float = 0.0, clip_norm: float = 0.0,
                     use_kernel: Optional[bool] = None,
-                    block: int = 4096, grad_batch: int = 0,
+                    block: Optional[int] = None, grad_batch: int = 0,
                     bucket_size: int = 0, model_cfg=None) -> Callable:
     """Mesh twin of ``train_step.build_train_step`` — same signature:
 

@@ -9,6 +9,18 @@ the W-times-larger stacked buffer).
 
 Grid: 1-D over flattened-parameter blocks. Mask lives in a [W, 1] VMEM
 block replicated to every grid step.
+
+Block rule (``choose_block``): the lane count per grid step is the
+largest multiple of 1,024 whose [W, block] input tile fits
+``TILE_BYTES``, capped at N (a block of N is the full dimension). A
+fixed 4,096 lanes made the call step-bound: qwen3-0.6b's 596,049,920
+lanes ran 145,520 grid steps of 48 KB at W = 2, each ~0.325 us (the
+fixed cost of a Pallas TPU grid step, 47.3 ms a call on a v5e), where
+the bytes alone take 8.7 ms at 819 GB/s. At 262,144 lanes the same call
+is 2,274 steps. The last block may be ragged: Pallas clips its writes
+at N, and every lane is reduced on its own, so what the last tile holds
+past N reaches no output lane. The stack is never padded (a pad would
+copy the whole [W, N] stack).
 """
 from __future__ import annotations
 
@@ -21,6 +33,25 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.mode import interpret_mode
 
+# VMEM budget of one [W, block] input tile. Compiled for a v5e under the
+# default scoped VMEM at N = 596,049,920: a 2 MiB tile compiles (f32
+# W = 2 at 262,144 lanes, bf16 W = 8 at 131,072), a 4 MiB tile is
+# refused with RESOURCE_EXHAUSTED (f32 W = 2 at 524,288, bf16 W = 8 at
+# 262,144).
+TILE_BYTES = 2 << 20
+# lane granule of a block smaller than N: the [N] f32 output's TPU layout
+# is tiled by 1,024 lanes (T(1024)), and Mosaic refuses a 128-lane block
+LANES = 1024
+
+
+def choose_block(w: int, n: int, itemsize: int) -> int:
+    """Lanes per grid step for a [w, n] stack of ``itemsize``-byte
+    elements: the largest multiple of ``LANES`` whose input tile fits
+    ``TILE_BYTES`` (at least one granule), or ``n`` when that is
+    smaller."""
+    fit = TILE_BYTES // (w * itemsize) // LANES * LANES
+    return min(max(fit, LANES), n)
+
 
 def _reduce_kernel(g_ref, m_ref, o_ref, *, inv_n: float):
     g = g_ref[...].astype(jnp.float32)              # [W, BN]
@@ -32,32 +63,31 @@ def _reduce_kernel(g_ref, m_ref, o_ref, *, inv_n: float):
 
 
 def backup_reduce(grads: jnp.ndarray, mask: jnp.ndarray, n_aggregate: int, *,
-                  block: int = 4096,
+                  block: Optional[int] = None,
                   interpret: Optional[bool] = None) -> jnp.ndarray:
     """grads: [W, N] stacked worker grads; mask: [W] -> [N] masked mean.
 
-    N may be any size: the flattened gradient is zero-padded up to the
-    block multiple for the grid and the padding is sliced off the output
-    (zeros reduce to zeros, so the padded lanes are inert).
+    N may be any size: the grid is ``cdiv(N, block)`` and a ragged last
+    block is clipped by Pallas, never padded. ``block=None`` takes
+    ``choose_block(W, N, grads.dtype.itemsize)``; an explicit ``block``
+    is capped at N. Each lane is reduced over the same rows in the same
+    order whatever the block, so the output does not depend on it.
     ``interpret=None`` follows the backend (``kernels.mode``).
     """
     w, n = grads.shape
+    if block is None:
+        block = choose_block(w, n, grads.dtype.itemsize)
     block = min(block, n)
-    pad = (-n) % block
-    if pad:
-        grads = jnp.pad(grads, ((0, 0), (0, pad)))
-    padded = n + pad
     kernel = functools.partial(_reduce_kernel, inv_n=1.0 / n_aggregate)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
-        grid=(padded // block,),
+        grid=(pl.cdiv(n, block),),
         in_specs=[
             pl.BlockSpec((w, block), lambda i: (0, i)),
             pl.BlockSpec((w, 1), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((padded,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n,), jnp.float32),
         interpret=interpret_mode(interpret),
         name="backup_reduce",           # the kernel's name on a device trace
     )(grads, mask.astype(jnp.float32).reshape(w, 1))
-    return out[:n] if pad else out

@@ -69,7 +69,7 @@ def reduce_then_psum(grads: jnp.ndarray, mask: jnp.ndarray,
                      tail: Optional[jnp.ndarray] = None,
                      use_kernel: bool = True,
                      interpret: Optional[bool] = None,
-                     block: int = 4096
+                     block: Optional[int] = None
                      ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
     """Bucketed masked reduce of [W, P] stacked grads, psum'd per bucket.
 
@@ -82,7 +82,8 @@ def reduce_then_psum(grads: jnp.ndarray, mask: jnp.ndarray,
 
     ``bucket`` is the lane count per collective (0 = single bucket);
     ``use_kernel`` picks the Pallas in-shard reduce vs the jnp dot;
-    ``block`` is the Pallas grid tile within each bucket.
+    ``block`` is the Pallas grid tile within each bucket (None: chosen
+    from the bucket's shape and dtype, ``backup_reduce.choose_block``).
     """
     w, p = grads.shape
     if mask.shape != (w,):

@@ -42,7 +42,7 @@ def wkv6(r, k, v, w, u, *, chunk=16):
 
 
 @functools.partial(jax.jit, static_argnames=("n_aggregate", "block"))
-def backup_reduce(grads, mask, n_aggregate, *, block=4096):
+def backup_reduce(grads, mask, n_aggregate, *, block=None):
     """grads: [W, N]; mask: [W] -> [N] = (1/N_agg) * sum_selected."""
     return _br.backup_reduce(grads, mask, n_aggregate, block=block,
                              interpret=interpret_mode())

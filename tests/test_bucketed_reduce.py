@@ -6,7 +6,7 @@ collective half of the SPMD engine's aggregation: cut the flattened
 per bucket, with monitoring scalars riding the last bucket. The oracle
 is ``ref_masked_mean`` — the dense jnp reduction the property tests
 hold every configuration to (random shapes, masks, bucket sizes, Pallas
-blocks that do NOT divide the bucket, i.e. the padding edges).
+blocks that do NOT divide the bucket, i.e. a ragged last grid block).
 
 Property tests use the ``hypothesis_stub`` shim: with hypothesis
 installed (requirements-dev.txt, the CI path) they fuzz; without it they
@@ -81,13 +81,16 @@ def test_single_worker_shortcut_matches_ref():
 
 def test_kernel_padding_edges():
     # P=50 lanes, bucket=16 -> ragged last bucket of 2 lanes, block=8
-    # does not divide it: backup_reduce's internal zero-padding edge
+    # does not divide it: backup_reduce's ragged last grid block
     grads, mask = _rand(1, 4, 50)
     _assert_matches_ref(grads, mask, 2, bucket=16, use_kernel=True,
                         interpret=True, block=8)
     # block larger than the whole bucket
     _assert_matches_ref(grads, mask, 2, bucket=6, use_kernel=True,
                         interpret=True, block=64)
+    # the default block, chosen from each bucket's own width
+    _assert_matches_ref(grads, mask, 2, bucket=16, use_kernel=True,
+                        interpret=True)
 
 
 def test_empty_flatten():
@@ -163,7 +166,7 @@ def test_property_jnp_bucketing_matches_ref(seed, w, p, bucket):
 @settings(max_examples=25, deadline=None)
 def test_property_kernel_bucketing_matches_ref(seed, w, p, bucket, block):
     # interpret-mode Pallas kernel per bucket, including blocks that do
-    # not divide the (possibly ragged) bucket width — the padding edges
+    # not divide the (possibly ragged) bucket width — ragged grid blocks
     grads, mask = _rand(seed, w, p)
     _assert_matches_ref(grads, mask, 2, bucket=bucket, use_kernel=True,
                         interpret=True, block=block)

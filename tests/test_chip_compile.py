@@ -7,6 +7,8 @@ Only one process at a time may load the TPU compiler's library, so the
 topology is described inside a module fixture (never at import) and
 every compile happens in this test process.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -67,6 +69,18 @@ def test_backup_reduce_compiles_native(one_chip, workers, dtype):
         lambda g, m: backup_reduce(g, m, workers - 1, interpret=False),
         grads, mask)
     assert "tpu_custom_call" in text
+    # the call's boundary, which backup_reduce_roofline finds it by: the
+    # stack and the mask go in as they are, the f32 mean comes out
+    hlo_dtype = {jnp.float32: "f32", jnp.bfloat16: "bf16"}[dtype]
+    stack = f"{hlo_dtype}[{workers},{NUM_PARAMS}]"
+    call = re.search(r"= f32\[(\d+)\]\S* custom-call\(.*operand_layout_"
+                     r"constraints=\{(\S+)\{1,0\}, f32\[(\d+),1\]\{1,0\}\}",
+                     text)
+    assert call, text
+    assert (int(call[1]), call[2], int(call[3])) \
+        == (NUM_PARAMS, stack, workers)
+    # a ragged last block is clipped, not padded: no copy of the stack
+    assert " pad(" not in text
 
 
 def test_reduce_then_psum_bucketed_compiles_native(topo):

@@ -112,8 +112,8 @@ def test_ssd_chunked_matches_scan():
 
 @pytest.mark.parametrize("w,n,block", [(8, 4096, 1024), (16, 8192, 4096),
                                        (3, 512, 512),
-                                       # non-multiple sizes: kernel pads the
-                                       # flattened grad to the block multiple
+                                       # non-multiple sizes: the last grid
+                                       # block is ragged, clipped at N
                                        (8, 5000, 1024), (4, 700, 256),
                                        (5, 3, 4096)])
 def test_backup_reduce_kernel(w, n, block):
@@ -125,6 +125,47 @@ def test_backup_reduce_kernel(w, n, block):
     expect = ref.reference_backup_reduce(g, mask, n_agg)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("w", [2, 3, 8])
+@pytest.mark.parametrize("size", ["ragged", "small"])
+def test_backup_reduce_default_block(w, dtype, size):
+    """The block chosen from the shape gives the 4,096-lane kernel's
+    output bit for bit: over several grid steps with a ragged last one,
+    and as one full-width block when N is below the chosen block."""
+    from repro.kernels.backup_reduce import choose_block
+    from repro.kernels.bucketed_reduce import ref_masked_mean
+    chosen = choose_block(w, 1 << 30, jnp.dtype(dtype).itemsize)
+    n = chosen + 1_000 + 37 if size == "ragged" else 3_000
+    g = _rand(jax.random.PRNGKey(w), (w, n), dtype)
+    mask = jnp.arange(w) != 1                  # worker 1 a straggler
+    n_agg = w - 1
+    out = ops.backup_reduce(g, mask, n_agg)
+    assert out.shape == (n,) and out.dtype == jnp.float32
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(ops.backup_reduce(g, mask, n_agg,
+                                                      block=4096)))
+    with jax.default_matmul_precision("highest"):
+        expect = ref_masked_mean(g, mask, n_agg)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
+                               rtol=1e-6, atol=1e-7)
+
+
+@pytest.mark.parametrize("w,n,itemsize,expect", [
+    (2, 596_049_920, 4, 262_144), (4, 596_049_920, 4, 131_072),
+    (8, 596_049_920, 2, 131_072), (3, 596_049_920, 4, 174_080),
+    (2, 1 << 20, 2, 524_288), (2, 262_143, 4, 262_143),
+    (6, 5_000, 4, 5_000), (1, 37, 4, 37)])
+def test_backup_reduce_block_rule(w, n, itemsize, expect):
+    from repro.kernels.backup_reduce import LANES, TILE_BYTES, choose_block
+    block = choose_block(w, n, itemsize)
+    assert block == expect
+    assert block % LANES == 0 or block == n
+    assert w * block * itemsize <= TILE_BYTES
+    assert block <= n
+    # the largest such block: one granule more overflows the budget
+    assert block == n or w * (block + LANES) * itemsize > TILE_BYTES
 
 
 def test_backup_reduce_matches_sync_backup_semantics():
