@@ -1,17 +1,19 @@
 """Operations and bytes from shapes: the yardstick's arithmetic.
 
-The dense-transformer part of the program's analytic FLOP model
-(``repro.analysis.perfmodel``: ``_avg_kv``, ``_attn_flops_per_tok``,
-``_mlp_flops_per_tok``, ``cell_flops``), copied here so that no change
-to the program can change how the benchmark counts. Conventions: a
-matrix product is 2*M*N*K; causal attention counts the average number
-of keys a query attends to; a training step is the forward pass plus
-twice it for the backward. Recomputation under remat is not counted:
-these are model FLOPs, the work the step requires.
+Model FLOPs belong to the architecture: ``train_flops_per_token``
+dispatches on ``s.model_type`` to ``archs/<model_type>.py``, which
+copies its part of the program's analytic FLOP model
+(``repro.analysis.perfmodel``), so that no change to the program can
+change how the benchmark counts. What every architecture shares stays
+here: the causal mask's average keys per query and the masked reduce's
+bytes. Conventions: a matrix product is 2*M*N*K; causal attention counts
+the average number of keys a query attends to; a training step is the
+forward pass plus twice it for the backward. Recomputation under remat
+is not counted: these are model FLOPs, the work the step requires.
 """
 from __future__ import annotations
 
-from chipbench.model_spec import Sizes
+from chipbench import spec
 
 
 def avg_kv(seq: int) -> float:
@@ -19,23 +21,9 @@ def avg_kv(seq: int) -> float:
     return (seq + 1) / 2.0
 
 
-def attn_flops_per_token(s: Sizes, s_kv: float) -> float:
-    d, h, kv, hd = s.d_model, s.heads, s.kv_heads, s.head_dim
-    proj = 2 * d * (h * hd) + 2 * 2 * d * (kv * hd) + 2 * (h * hd) * d
-    scores = 2 * s_kv * h * hd * 2               # Q K^T and P V
-    return proj + scores
-
-
-def mlp_flops_per_token(s: Sizes) -> float:
-    return 2.0 * s.d_model * s.d_ff * 3          # gate, up, down (SwiGLU)
-
-
-def train_flops_per_token(s: Sizes, seq: int) -> float:
+def train_flops_per_token(s, seq: int) -> float:
     """Forward + backward model FLOPs per trained token at ``seq``."""
-    layers = s.layers * (attn_flops_per_token(s, avg_kv(seq))
-                         + mlp_flops_per_token(s))
-    head = 2.0 * s.d_model * s.vocab             # logits
-    return 3.0 * (layers + head)
+    return spec.arch(s.model_type).train_flops_per_token(s, seq)
 
 
 def backup_reduce_bytes(w_local: int, params: int) -> float:

@@ -128,7 +128,8 @@ def _compile_counter() -> _CompileCounter:
 
 def _program_config(cell: spec.Cell, traffic: feed.Traffic, seed: int):
     """The TrainConfig the training CLI builds for this cell, with the
-    model's sizes taken from the configuration file."""
+    model's sizes taken from the configuration file by its architecture
+    (``archs/<model_type>.py``: ``program_model``)."""
     from repro.launch.train import build_config
 
     run = cell.config["run"]
@@ -148,20 +149,12 @@ def _program_config(cell: spec.Cell, traffic: feed.Traffic, seed: int):
         straggler_backend=run["straggler_backend"], prefetch_depth=1,
         faults=None, fault_seed=0, supervise=False, max_restarts=0)
     cfg = build_config(args)
-    s = model_spec.sizes(cell.config)
-    model = dataclasses.replace(
-        cfg.model, num_layers=s.layers, d_model=s.d_model, num_heads=s.heads,
-        num_kv_heads=s.kv_heads, head_dim=s.head_dim, d_ff=s.d_ff,
-        vocab_size=s.vocab, rope_theta=s.rope_theta, norm_eps=s.eps,
-        dtype=s.dtype)
-    expect = {"family": "dense", "attention_kind": "gqa", "qk_norm": True,
-              "hidden_act": "swiglu", "tie_embeddings": True,
-              "use_bias": False, "sliding_window": 0,
-              "padded_vocab": s.vocab}
-    found = {k: getattr(model, k) for k in expect}
-    if found != expect:
+    s = model_spec.sizes(cell.config, cell.bench_dir)
+    try:
+        model = spec.arch(s.model_type).program_model(cfg.model, s)
+    except ValueError as e:
         raise ValueError(f"the program's {run['arch']} is not the model of "
-                         f"{cell.config_name}: {found} != {expect}")
+                         f"{cell.config_name}: {e}") from None
     o = cfg.optimizer
     stated = (opt["name"], float(opt["decay"]), float(opt["momentum"]),
               float(opt["eps"]), float(opt["ema_decay"]),
@@ -215,7 +208,7 @@ class SetUp:
     tr: Any
     rec: _Recorder
     traffic: feed.Traffic
-    sizes: model_spec.Sizes
+    sizes: Any                   # archs/<model_type>.py: sizes()
     key: Any
     prog: Dict[str, Any]
     devices: List[Any]
@@ -248,7 +241,7 @@ def set_up(cell: spec.Cell, seed: int, *, require_chip: bool = True,
     marks.append(("program imports", time.perf_counter()))
     devices = require_chips(cell.chips) if require_chip else jax.devices()
     traffic = feed.traffic(cell.traffic)
-    sizes = model_spec.sizes(cell.config)
+    sizes = model_spec.sizes(cell.config, cell.bench_dir)
     cfg = _program_config(cell, traffic, feed.derived_seed(seed, 1))
     key = jax.random.PRNGKey(feed.derived_seed(seed, 2, bits=32))
     decay = float(cell.config["run"]["optimizer"]["decay"])

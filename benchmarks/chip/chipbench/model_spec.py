@@ -1,88 +1,56 @@
-"""A configuration file's sizes, and the parameter tree they make.
+"""A configuration file's sizes and parameter tree, by architecture.
 
 The configuration files hold a model's published ``config.json`` keys
-(Hugging Face names) and a ``run`` section with how the job runs. This
-module reads the sizes from them and lays out the parameter tree in the
-form the trainer takes: one leaf per weight, the decoder layers stacked
-on a leading axis. The benchmark makes the weights itself, from the
-seed, so that the plain reference can make the same weights again
-without taking anything from the program.
+(Hugging Face names) and a ``run`` section with how the job runs. What
+belongs to one architecture lives in ``archs/<model_type>.py``, found by
+the file's ``model_type`` (``spec.arch``), so that a configuration of a
+new architecture is added by adding files. Such a file brings:
+
+    sizes(config)       a frozen, hashable dataclass of the sizes, with
+                        at least ``model_type``, ``vocab``, ``dtype`` and
+                        ``param_count``
+    leaf_shapes(s)      the parameter tree as the trainer takes it, one
+                        shape per weight; the leaves under each
+                        ``seg_<kind>`` key lead with that segment's
+                        layer count
+    program_model(m, s) the program's ``ModelConfig`` ``m``, as the
+                        training CLI built it, with this file's sizes;
+                        raises where the program's model is another
+    embed, segments, head_loss
+                        the plain reference's model (``reference.py``
+                        says what each takes)
+    train_flops_per_token(s, seq)
+                        model FLOPs of one trained token
+                        (``flops.py``)
+    TINY                the config keys a CPU test cuts (``tiny.py``)
+
+What stays here is generic: the dispatch, the weights made from the
+seed over any layout (``init_fn``), and the comparison of two layouts.
+The benchmark makes the weights itself, from the seed, so that the
+plain reference can make the same weights again without taking
+anything from the program.
 """
 from __future__ import annotations
 
-import dataclasses
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from chipbench import spec
+
 DTYPES = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}
 
 
-@dataclasses.dataclass(frozen=True)
-class Sizes:
-    layers: int
-    d_model: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    rope_theta: float
-    eps: float
-    dtype: str
-
-    @property
-    def param_count(self) -> int:
-        d, hd = self.d_model, self.head_dim
-        attn = d * self.heads * hd * 2 + d * self.kv_heads * hd * 2 + 2 * hd
-        mlp = 3 * d * self.d_ff
-        return (self.vocab * d + d
-                + self.layers * (attn + mlp + 2 * d))
+def sizes(config: Dict[str, Any], bench_dir: str = spec.BENCH_DIR):
+    """The sizes of the configuration's architecture
+    (``archs/<model_type>.py``)."""
+    return spec.arch(config.get("model_type"), bench_dir).sizes(config)
 
 
-def sizes(config: Dict[str, Any]) -> Sizes:
-    """The sizes of a Qwen3-style dense decoder from its config keys."""
-    if config.get("model_type") != "qwen3":
-        raise ValueError(f"model_type {config.get('model_type')!r}: only "
-                         f"qwen3 (dense, qk-norm, SwiGLU) is laid out here")
-    if not config["tie_word_embeddings"] or config["hidden_act"] != "silu":
-        raise ValueError("expected tied embeddings and a SiLU-gated MLP")
-    if config.get("attention_bias") or config.get("use_sliding_window"):
-        raise ValueError("attention biases and sliding windows are not "
-                         "laid out here")
-    return Sizes(layers=config["num_hidden_layers"],
-                 d_model=config["hidden_size"],
-                 heads=config["num_attention_heads"],
-                 kv_heads=config["num_key_value_heads"],
-                 head_dim=config["head_dim"],
-                 d_ff=config["intermediate_size"],
-                 vocab=config["vocab_size"],
-                 rope_theta=float(config["rope_theta"]),
-                 eps=float(config["rms_norm_eps"]),
-                 dtype=config["torch_dtype"])
-
-
-def leaf_shapes(s: Sizes) -> Dict[str, Any]:
-    """Nested dict of leaf shapes; layer leaves lead with ``layers``."""
-    L, d, hd, f = s.layers, s.d_model, s.head_dim, s.d_ff
-    return {
-        "embed": {"embedding": (s.vocab, d)},
-        "final_norm": {"scale": (d,)},
-        "seg_dense": {
-            "ln1": {"scale": (L, d)},
-            "attn": {"wq": {"w": (L, d, s.heads * hd)},
-                     "wk": {"w": (L, d, s.kv_heads * hd)},
-                     "wv": {"w": (L, d, s.kv_heads * hd)},
-                     "wo": {"w": (L, s.heads * hd, d)},
-                     "q_norm": {"scale": (L, hd)},
-                     "k_norm": {"scale": (L, hd)}},
-            "ln2": {"scale": (L, d)},
-            "mlp": {"w_up": {"w": (L, d, f)},
-                    "w_down": {"w": (L, f, d)},
-                    "w_gate": {"w": (L, d, f)}},
-        },
-    }
+def leaf_shapes(s) -> Dict[str, Any]:
+    """Nested dict of leaf shapes; segment leaves lead with its layers."""
+    return spec.arch(s.model_type).leaf_shapes(s)
 
 
 def _is_shape(x) -> bool:
@@ -92,7 +60,7 @@ def _is_shape(x) -> bool:
 GRID_EXPONENT = 12   # weights are k * 2**-12 with k uniform in [-128, 128)
 
 
-def init_fn(s: Sizes):
+def init_fn(s):
     """key -> parameters: every matrix uniform on the grid k * 2**-12,
     k in [-128, 128) (std 0.018, near the published
     ``initializer_range`` of 0.02), every norm scale 1. Each leaf draws

@@ -5,27 +5,40 @@ from the seed by ``model_spec.init_fn``), the same batches (``feed``)
 and the straggler arrival times the run recorded, selects the fastest N
 workers itself, and runs three steps of
 
-    loss_w  = mean over worker w's rows of the mean next-token loss
+    loss_w  = mean over worker w's rows of the mean next-token loss,
+              plus the layers' auxiliary terms
     g       = (1/N) * sum over the selected w of grad loss_w
     ms      = decay * ms + (1 - decay) * g^2
     mom     = momentum * mom + lr * g / sqrt(ms + eps)
     theta   = round_to_param_dtype(theta - mom)
     ema     = ema_decay * ema + (1 - ema_decay) * theta
 
-with ``lr`` the configured rate times N (the paper's rule). The model is
-Qwen3's decoder as published: RMSNorm before attention and MLP, q/k
-RMSNorm per head, rotary embedding on the two halves of each head,
-grouped-query causal attention, a SiLU-gated MLP, a final RMSNorm and
-the tied embedding as output head. Every matrix product runs at
-``Precision.HIGHEST``, so it is float32 on the TPU too.
+with ``lr`` the configured rate times N (the paper's rule).
+
+This file is generic; the model is the architecture's
+(``archs/<model_type>.py``), in three parts over float32 parameters:
+
+    embed(p, tokens, s) -> x            p: the parameters outside the
+                                        segments
+    segments(s) -> [(seg_key, layer)]   in order; ``params[seg_key]``
+                                        stacks the segment's layers on a
+                                        leading axis, and
+                                        ``layer(p_l, x, s, cast)`` gives
+                                        x, or (x, aux) with aux a scalar
+                                        added to the loss
+    head_loss(p, x, labels, s, cast)    the mean next-token loss
+
+``cast`` goes around both operands of every matrix product (see
+below); the architecture runs each at ``Precision.HIGHEST``, so it is
+float32 on the TPU too.
 
 Parameters are stored in the configured dtype between steps, as the
 configuration states; everything else is float32. Memory: gradients
 are taken worker by worker and, inside a worker, layer by layer (the
 forward keeps each layer's input; the backward recomputes one layer at
-a time and adds its gradient into the accumulator in place), so a
-step holds the weights, the optimizer state, the EMA, one float32
-gradient and one worker's activations.
+a time, segment after segment in reverse, and adds its gradient into
+the accumulator in place), so a step holds the weights, the optimizer
+state, the EMA, one float32 gradient and one worker's activations.
 
 ``precision="low"`` is the control: the same arithmetic with both
 operands of every matrix product, and the cotangents flowing back into
@@ -35,16 +48,13 @@ e4m3 under bfloat16 parameters, bfloat16 under float32 ones).
 from __future__ import annotations
 
 import functools
-import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from chipbench import model_spec
-
-HIGHEST = jax.lax.Precision.HIGHEST
+from chipbench import model_spec, spec
 
 
 # ---------------------------------------------------------------------------
@@ -83,66 +93,26 @@ def operand_cast(dtype: str, precision: str):
 
 
 # ---------------------------------------------------------------------------
-# The model
+# One worker's gradient, layer by layer
 # ---------------------------------------------------------------------------
-
-
-def _rms(x, scale, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
-        * scale
-
-
-def _rope(x, theta):
-    """x: [R, S, heads, hd]; rotate the halves (x1, x2) of each head."""
-    s, hd = x.shape[1], x.shape[-1]
-    inv = 1.0 / theta ** (np.arange(0, hd, 2, dtype=np.float64) / hd)
-    ang = np.arange(s, dtype=np.float64)[:, None] * inv[None, :]
-    cos = jnp.asarray(np.cos(ang), jnp.float32)[None, :, None, :]
-    sin = jnp.asarray(np.sin(ang), jnp.float32)[None, :, None, :]
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _layer(p, x, s: model_spec.Sizes, cast):
-    def mm(eq, a, b):
-        return jnp.einsum(eq, cast(a), cast(b), precision=HIGHEST)
-
-    r, n, _ = x.shape
-    hd = s.head_dim
-    h = _rms(x, p["ln1"]["scale"], s.eps)
-    a = p["attn"]
-    q = mm("rnd,de->rne", h, a["wq"]["w"]).reshape(r, n, s.heads, hd)
-    k = mm("rnd,de->rne", h, a["wk"]["w"]).reshape(r, n, s.kv_heads, hd)
-    v = mm("rnd,de->rne", h, a["wv"]["w"]).reshape(r, n, s.kv_heads, hd)
-    q = _rope(_rms(q, a["q_norm"]["scale"], s.eps), s.rope_theta)
-    k = _rope(_rms(k, a["k_norm"]["scale"], s.eps), s.rope_theta)
-    group = s.heads // s.kv_heads
-    k = jnp.repeat(k, group, axis=2)
-    v = jnp.repeat(v, group, axis=2)
-    scores = mm("rqhd,rkhd->rhqk", q, k) / math.sqrt(hd)
-    causal = np.tril(np.ones((n, n), bool))
-    scores = jnp.where(causal[None, None], scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1)
-    o = mm("rhqk,rkhd->rqhd", probs, v).reshape(r, n, s.heads * hd)
-    x = x + mm("rne,ed->rnd", o, a["wo"]["w"])
-    h = _rms(x, p["ln2"]["scale"], s.eps)
-    m = p["mlp"]
-    gate = mm("rnd,df->rnf", h, m["w_gate"]["w"])
-    up = mm("rnd,df->rnf", h, m["w_up"]["w"])
-    return x + mm("rnf,fd->rnd", jax.nn.silu(gate) * up, m["w_down"]["w"])
-
-
-def _head_loss(x, final_scale, emb, labels, s: model_spec.Sizes, cast):
-    """Mean over rows of each row's mean next-token loss."""
-    h = _rms(x, final_scale, s.eps)
-    logits = jnp.einsum("rnd,vd->rnv", cast(h), cast(emb), precision=HIGHEST)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    label_logit = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
-    return jnp.mean(jnp.mean(lse - label_logit, axis=-1))
 
 
 def _f32(tree):
     return jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), tree)
+
+
+def _outside(tree):
+    """The parameters outside the layer-stacked segments."""
+    return {k: v for k, v in tree.items() if not k.startswith("seg_")}
+
+
+def _with_aux(layer, s, cast):
+    """``layer`` as (p, x) -> (x, aux), aux 0 where it gives none."""
+    def f(p, x):
+        out = layer(p, x, s, cast)
+        return out if isinstance(out, tuple) else (
+            out, jnp.zeros((), jnp.float32))
+    return f
 
 
 def _worker_grad(acc, params, tokens, labels, weight, *, s, cast):
@@ -150,39 +120,49 @@ def _worker_grad(acc, params, tokens, labels, weight, *, s, cast):
 
     Layer by layer: the forward keeps each layer's input, the backward
     recomputes one layer and adds its gradient into ``acc`` at that
-    layer's index (acc is donated, so the update is in place)."""
-    emb = params["embed"]["embedding"]
-    seg = params["seg_dense"]
-    x0 = jnp.take(emb, tokens, axis=0).astype(jnp.float32)
+    layer's index (acc is donated, so the update is in place). The
+    embedding's gradient from the rows it gave is added to the head's
+    gradient, then to ``acc``."""
+    arch = spec.arch(s.model_type)
+    segments = [(key, _with_aux(layer, s, cast))
+                for key, layer in arch.segments(s)]
+    outer = _f32(_outside(params))
+    weight = jnp.asarray(weight, jnp.float32)
+    x, embed_vjp = jax.vjp(lambda p: arch.embed(p, tokens, s), outer)
+    aux = jnp.zeros((), jnp.float32)
+    inputs = []
+    for key, layer in segments:
+        def fwd(carry, p_l, layer=layer):
+            x, aux = carry
+            y, a = layer(_f32(p_l), x)
+            return (y, aux + a), x
 
-    def fwd(x, p_l):
-        return _layer(_f32(p_l), x, s, cast), x
-
-    x_last, xs = jax.lax.scan(fwd, x0, seg)
+        (x, aux), xs = jax.lax.scan(fwd, (x, aux), params[key])
+        inputs.append(xs)
     loss, head_vjp = jax.vjp(
-        lambda x, f, e: _head_loss(x, f, e, labels, s, cast),
-        x_last, params["final_norm"]["scale"].astype(jnp.float32),
-        emb.astype(jnp.float32))
-    ct, g_final, g_emb = head_vjp(jnp.asarray(weight, jnp.float32))
+        lambda x, p: arch.head_loss(p, x, labels, s, cast), x, outer)
+    ct, g_outer = head_vjp(weight)
 
-    def bwd(carry, inputs):
-        ct, acc_seg, i = carry
-        p_l, x_in = inputs
-        _, vjp = jax.vjp(lambda p, x: _layer(p, x, s, cast), _f32(p_l), x_in)
-        g_l, ct_in = vjp(ct)
-        acc_seg = jax.tree_util.tree_map(
-            lambda a, g: jax.lax.dynamic_update_index_in_dim(
-                a, jax.lax.dynamic_index_in_dim(a, i, 0, False) + g, i, 0),
-            acc_seg, g_l)
-        return (ct_in, acc_seg, i - 1), None
+    acc = dict(acc)
+    for (key, layer), xs in reversed(list(zip(segments, inputs))):
+        def bwd(carry, inputs, layer=layer):
+            ct, acc_seg, i = carry
+            p_l, x_in = inputs
+            _, vjp = jax.vjp(layer, _f32(p_l), x_in)
+            g_l, ct_in = vjp((ct, weight))
+            acc_seg = jax.tree_util.tree_map(
+                lambda a, g: jax.lax.dynamic_update_index_in_dim(
+                    a, jax.lax.dynamic_index_in_dim(a, i, 0, False) + g,
+                    i, 0),
+                acc_seg, g_l)
+            return (ct_in, acc_seg, i - 1), None
 
-    (ct0, acc_seg, _), _ = jax.lax.scan(
-        bwd, (ct, acc["seg_dense"], s.layers - 1), (seg, xs), reverse=True)
-    g_emb = g_emb.at[tokens.reshape(-1)].add(ct0.reshape(-1, s.d_model))
-    acc = {"embed": {"embedding": acc["embed"]["embedding"] + g_emb},
-           "final_norm": {"scale": acc["final_norm"]["scale"] + g_final},
-           "seg_dense": acc_seg}
-    return acc, loss
+        count = jax.tree_util.tree_leaves(params[key])[0].shape[0]
+        (ct, acc[key], _), _ = jax.lax.scan(
+            bwd, (ct, acc[key], count - 1), (params[key], xs), reverse=True)
+    g_outer = jax.tree_util.tree_map(jnp.add, g_outer, embed_vjp(ct)[0])
+    acc.update(jax.tree_util.tree_map(jnp.add, _outside(acc), g_outer))
+    return acc, loss + aux
 
 
 def _update(params, ms, mom, ema, g, *, lr, decay, momentum, eps, ema_decay,
@@ -209,7 +189,7 @@ SKETCH_DIM = 8
 
 
 def _stacked(name: str) -> bool:
-    return name.startswith("['seg_dense']")
+    return name.startswith("['seg_")
 
 
 def tensor_norms(tree):
@@ -311,7 +291,7 @@ to_f32 = jax.jit(_f32)
 
 
 @functools.lru_cache(maxsize=None)
-def _programs(s: model_spec.Sizes, precision: str, lr: float, decay: float,
+def _programs(s, precision: str, lr: float, decay: float,
               momentum: float, eps: float, ema_decay: float):
     """The reference's jitted programs, built once per process for each
     model and optimizer setting (they hold no arrays)."""

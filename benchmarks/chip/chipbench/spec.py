@@ -9,6 +9,11 @@ cell by adding files and an entry, and edits nothing here:
     traffic/<traffic>.json    the traffic mix, read by ``feed.py``
     limits/<workload>.json    the limits of the correctness comparison
     metrics/<metric>.py       the reader of one per-layer metric
+    archs/<model_type>.py     one architecture: its sizes, parameter
+                              tree, plain reference and FLOPs, found by
+                              the configuration's ``model_type``
+                              (``chipbench/model_spec.py`` says what
+                              such a file holds)
 """
 from __future__ import annotations
 
@@ -17,6 +22,7 @@ import importlib.util
 import json
 import os
 import re
+import sys
 from typing import Any, Callable, Dict, List, Optional
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -36,6 +42,7 @@ class Cell:
     limits: Dict[str, Any]
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
+    bench_dir: str = BENCH_DIR
 
 
 def load_benchmark(root: str = ROOT) -> Dict[str, Any]:
@@ -73,7 +80,8 @@ def resolve(workload: str, bench: Optional[Dict[str, Any]] = None,
     return Cell(
         name=workload, config_name=w["config"], chips=int(w["chips"]), config=config, traffic=traffic, limits=limits,
         end_to_end=[m for m in bench["end_to_end"] if reports(m, workload)],
-        per_layer=[m for m in bench["per_layer"] if reports(m, workload)])
+        per_layer=[m for m in bench["per_layer"] if reports(m, workload)],
+        bench_dir=bench_dir)
 
 
 def metric_reader(name: str, bench_dir: str = BENCH_DIR) -> Callable:
@@ -85,3 +93,26 @@ def metric_reader(name: str, bench_dir: str = BENCH_DIR) -> Callable:
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module.read
+
+
+_ARCHS: Dict[str, Any] = {}
+
+
+def arch(model_type: str, bench_dir: str = BENCH_DIR):
+    """The module ``archs/<model_type>.py``: everything the benchmark
+    knows of one architecture. Loaded once per process, from the
+    directory of the first call that asks for it; later calls get that
+    module whatever directory they name."""
+    if model_type not in _ARCHS:
+        path = os.path.join(bench_dir, "archs", f"{model_type}.py")
+        if not (isinstance(model_type, str) and NAME_RE.match(model_type)
+                and os.path.isfile(path)):
+            raise ValueError(f"model_type {model_type!r}: no architecture "
+                             f"file archs/{model_type}.py in {bench_dir}")
+        name = "chipbench_arch_" + re.sub(r"\W", "_", model_type)
+        spec = importlib.util.spec_from_file_location(name, path)
+        module = importlib.util.module_from_spec(spec)
+        sys.modules[name] = module        # dataclasses look the module up
+        spec.loader.exec_module(module)
+        _ARCHS[model_type] = module
+    return _ARCHS[model_type]

@@ -1,9 +1,11 @@
 """A cell's configuration cut to a size a CPU test run holds.
 
-Every key but the widths, depth and vocabulary is the real cell's, so
-the tests drive the same harness, trainer path and comparison. The
-limits are the tiny model's own (``fixtures/tiny_limits.json``), set by
-the same rule as the chip cells' from CPU readings of it.
+The architecture's ``TINY`` (``archs/<model_type>.py``) names the keys
+cut: the widths, depth and vocabulary. Every other key is the real
+cell's, so the tests drive the same harness, trainer path and
+comparison. The limits are the tiny model's own
+(``fixtures/tiny/<workload>.json``), set by the same rule as the chip
+cells' from CPU readings of it.
 """
 from __future__ import annotations
 
@@ -14,18 +16,14 @@ import os
 
 from chipbench import spec
 
-SIZES = {"num_hidden_layers": 2, "hidden_size": 64, "num_attention_heads": 4,
-         "num_key_value_heads": 2, "head_dim": 16, "intermediate_size": 128,
-         "vocab_size": 512}
-LIMITS = os.path.join(spec.BENCH_DIR, "fixtures", "tiny_limits.json")
 
-
-def cell(workload: str) -> spec.Cell:
-    real = spec.resolve(workload)
+def cell(workload: str, root: str = spec.ROOT) -> spec.Cell:
+    real = spec.resolve(workload, root=root)
     config = copy.deepcopy(real.config)
-    config.update(SIZES)
+    config.update(spec.arch(config["model_type"], real.bench_dir).TINY)
     traffic = dict(real.traffic, seq_len=16, steps_per_block=2)
-    with open(LIMITS) as f:
-        limits = json.load(f)[workload]
+    path = os.path.join(real.bench_dir, "fixtures", "tiny", workload + ".json")
+    with open(path) as f:
+        limits = json.load(f)
     return dataclasses.replace(real, name=f"tiny.{workload}", config=config,
                                traffic=traffic, limits=limits)

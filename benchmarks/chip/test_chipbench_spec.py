@@ -1,19 +1,25 @@
 """BENCHMARK.json against the benchmark's contract, and every name in it
-resolved to its file, so that a cell, traffic mix or metric is added by
-adding files and an entry."""
+resolved to its file, so that a cell, traffic mix, metric or
+architecture is added by adding files and an entry."""
+import hashlib
 import json
 import os
 import re
 import shutil
 import subprocess
 import sys
+import time
 
+import jax
+import numpy as np
 import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
+sys.path.insert(1, os.path.join(os.path.dirname(os.path.dirname(HERE)), "src"))
 
-from chipbench import check, feed, model_spec, spec  # noqa: E402
+from chipbench import check, feed, flops, harness, model_spec  # noqa: E402
+from chipbench import reference, spec, tiny  # noqa: E402
 
 BENCH = spec.load_benchmark()
 TEXT_RE = re.compile(r"^[^\n\t]{1,200}$")
@@ -155,6 +161,136 @@ def test_a_cell_is_added_by_files_and_an_entry(tmp_path):
     assert [m["name"] for m in cell.per_layer][-1] == "steps.count"
     reader = spec.metric_reader("steps.count", str(bench_dir))
     assert reader(type("ctx", (), {"steps": 7})) == 7
+
+
+def _hashes(top):
+    """sha256 of every file under ``top``, by its path there."""
+    out = {}
+    for d, _, files in os.walk(top):
+        if "__pycache__" not in d:
+            for f in files:
+                path = os.path.join(d, f)
+                with open(path, "rb") as fh:
+                    out[os.path.relpath(path, top)] = hashlib.sha256(
+                        fh.read()).hexdigest()
+    return out
+
+
+UNTIED = "qwen3-untied.sim1.seq32x2"
+# The tiny cell's limits, set by calibrate.limits from CPU readings of
+# it: seeds 31-42, the control on all 12, the faults on the first 3.
+UNTIED_TINY_LIMITS = {"loss_gap": 0.00043, "grad_norm_gap": 0.084,
+                      "param_change_gap": 0.1, "ema_change_gap": 0.082,
+                      "grad_sketch_gap": 0.065, "param_sketch_gap": 0.16,
+                      "ema_sketch_gap": 0.17}
+
+
+@pytest.fixture(scope="module")
+def untied(tmp_path_factory):
+    """A copy of the benchmark with a test-only architecture added by
+    files alone: ``archs/qwen3_untied.py`` (fixtures/archs), a
+    configuration of that ``model_type`` with an untied head, a traffic
+    mix, the cell's limits, its tiny limits and the BENCHMARK.json
+    entries. Returns the copy's root and the hashes of the files the
+    benchmark's directory had before."""
+    root = tmp_path_factory.mktemp("untied") / "checkout"
+    bench_dir = root / BENCH["paths"][0]
+    shutil.copytree(HERE, bench_dir, ignore=shutil.ignore_patterns(
+        "__pycache__", ".*"))
+    before = _hashes(bench_dir)
+    shutil.copy(os.path.join(HERE, "fixtures", "archs", "qwen3_untied.py"),
+                bench_dir / "archs")
+    with open(os.path.join(HERE, "configs", "qwen3-0.6b.sim-1chip.json")) as f:
+        config = json.load(f)
+    config.update(model_type="qwen3_untied", tie_word_embeddings=False)
+    (bench_dir / "configs" / "qwen3-untied.sim-1chip.json").write_text(
+        json.dumps(config, indent=1))
+    (bench_dir / "traffic" / "n3b1.seq32x2.json").write_text(json.dumps(
+        {"workers": 3, "backups": 1, "rows_per_worker": 2, "seq_len": 32,
+         "tokens": "uniform", "steps_per_block": 8}))
+    (bench_dir / "limits" / f"{UNTIED}.json").write_text(json.dumps(
+        {"limits": {"loss_gap": 0.001}}))
+    (bench_dir / "fixtures" / "tiny" / f"{UNTIED}.json").write_text(
+        json.dumps({"limits": UNTIED_TINY_LIMITS}))
+    bench = json.loads(json.dumps(BENCH))
+    bench["configs"].append({
+        "name": "qwen3-untied.sim-1chip", "source": "a test",
+        "file": f"{BENCH['paths'][0]}/configs/qwen3-untied.sim-1chip.json",
+        "reduced": ["tie_word_embeddings"], "why": "a test architecture"})
+    bench["workloads"].append({"name": UNTIED,
+                               "config": "qwen3-untied.sim-1chip",
+                               "traffic": "n3b1.seq32x2", "chips": 1,
+                               "why": "a test cell"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    spec.arch("qwen3_untied", str(bench_dir))
+    return root, before
+
+
+def _unedited(root, before):
+    """No file the benchmark had is edited; BENCHMARK.json only gains
+    entries."""
+    after = _hashes(root / BENCH["paths"][0])
+    assert {k: after[k] for k in before} == before
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    for group, value in BENCH.items():
+        grows = group in ("configs", "workloads", "end_to_end", "per_layer")
+        assert (bench[group][:len(value)] if grows else bench[group]) == value
+
+
+def test_an_architecture_is_added_by_files_and_an_entry(untied):
+    """The new cell resolves, and its architecture gives sizes, a
+    layout, a FLOP count and the reference's readings; no file the
+    benchmark had is edited, and BENCHMARK.json only gains entries."""
+    root, before = untied
+    cell = spec.resolve(UNTIED, root=str(root))
+    s = model_spec.sizes(cell.config, cell.bench_dir)
+    assert s.model_type == "qwen3_untied"
+    # qwen3-0.6b's 596,049,920 and a 1,024 x 151,936 head
+    assert s.param_count == 596_049_920 + 1024 * 151_936
+    shapes = model_spec.leaf_shapes(s)
+    assert shapes["lm_head"] == {"w": (1024, 151_936)}
+    assert sum(int(np.prod(x)) for x in jax.tree_util.tree_leaves(
+        shapes, is_leaf=lambda x: isinstance(x, tuple))) == s.param_count
+    # the head's logits count the same, tied or not
+    assert flops.train_flops_per_token(s, 1024) == 3_928_571_904
+    small = tiny.cell(UNTIED, root=str(root))
+    t = feed.traffic(small.traffic)
+    data = feed.Feed(t, small.config["vocab_size"], 5)
+    got = reference.run(small.config, t, jax.random.PRNGKey(5),
+                        [data.batch(i) for i in range(3)],
+                        [np.arange(t.total_workers, dtype=float)] * 3)
+    assert len(got["losses"]) == 3 and all(map(np.isfinite, got["losses"]))
+    assert "['lm_head']['w']" in got["grad"]
+    assert "['seg_dense']['mlp']['w_up']['w'][1]" in got["param_change"]
+    _unedited(root, before)
+
+
+def test_an_added_architecture_runs_correct_and_its_control_does_not(untied):
+    """The new cell through ``harness.run_cell`` at the tiny size, the
+    program's qwen3 with an untied head: correct; the fp8 control in
+    the program's place: not correct."""
+    root, before = untied
+    cell = tiny.cell(UNTIED, root=str(root))
+    run = harness.run_cell(cell, 31, 0.2, False, t0=time.perf_counter(),
+                           require_chip=False)
+    assert run.result["correct"], run.report
+    s = harness.set_up(cell, 32, require_chip=False)
+    assert "lm_head" in s.tr.params
+    arrivals = list(s.rec.arrivals)
+    s.tr = s.rec = None
+    ref = harness.reference_readings(cell, s, 32, arrivals)
+    ctl = harness.reference_readings(cell, s, 32, arrivals, precision="low")
+    limits = cell.limits["limits"]
+    assert check.passed(check.judge(check.gaps(s.prog, ref), limits))
+    assert not check.passed(check.judge(check.gaps(ctl, ref), limits))
+    _unedited(root, before)
+
+
+def test_an_unknown_model_type_names_its_file():
+    config = dict(spec.resolve(BENCH["workloads"][0]["name"]).config,
+                  model_type="no_such_arch")
+    with pytest.raises(ValueError, match=r"archs/no_such_arch\.py"):
+        model_spec.sizes(config)
 
 
 def test_command_fails_without_a_tpu():
